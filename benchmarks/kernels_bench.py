@@ -30,14 +30,16 @@ def run():
     k = jax.random.normal(key, (1, 2, 128, 64))
     v = jax.random.normal(key, (1, 2, 128, 64))
     b.row("flash_attention_us", fmt(timeit(
-        lambda *a: flash_attention(*a, block_q=64, block_k=64), q, k, v), 0))
+        lambda *a: flash_attention(*a, block_q=64, block_k=64,
+                                   interpret=True), q, k, v), 0))
     b.row("flash_ref_us", fmt(timeit(R.flash_ref, q, k, v), 0))
 
     qd = jax.random.normal(key, (2, 4, 64))
     kc = jax.random.normal(key, (2, 2, 256, 64))
     lens = jnp.asarray([128, 256])
     b.row("decode_attention_us", fmt(timeit(
-        lambda *a: decode_attention(*a, block_k=128), qd, kc, kc, lens), 0))
+        lambda *a: decode_attention(*a, block_k=128, interpret=True),
+        qd, kc, kc, lens), 0))
     b.row("decode_ref_us", fmt(timeit(R.decode_ref, qd, kc, kc, lens), 0))
 
     # ragged paged decode at 1-of-4 occupancy: one 64-token row, three
@@ -53,14 +55,15 @@ def run():
     tables = jnp.arange(B4 * P, dtype=jnp.int32).reshape(B4, P)
     lens4 = jnp.asarray([64, 0, 0, 0], jnp.int32)
     b.row("ragged_paged_decode_us", fmt(timeit(
-        lambda *a: ragged_paged_decode(*a), q4, pool_k, pool_v, tables,
-        lens4), 0))
+        lambda *a: ragged_paged_decode(*a, interpret=True),
+        q4, pool_k, pool_v, tables, lens4), 0))
     gk = jnp.moveaxis(pool_k[tables], 2, 1).reshape(B4, kvH, P * page, hd)
     gv = jnp.moveaxis(pool_v[tables], 2, 1).reshape(B4, kvH, P * page, hd)
     b.row("ragged_gathered_ref_us", fmt(timeit(
         R.decode_ref, q4, gk, gv, lens4), 0))
     np.testing.assert_allclose(
-        np.asarray(ragged_paged_decode(q4, pool_k, pool_v, tables, lens4))[0],
+        np.asarray(ragged_paged_decode(q4, pool_k, pool_v, tables, lens4,
+                                       interpret=True))[0],
         np.asarray(R.decode_ref(q4, gk, gv, lens4))[0],
         rtol=2e-5, atol=2e-5)
     kv_elt = 2 * kvH * page * hd * 4          # k+v page pair, fp32 bytes
@@ -76,7 +79,8 @@ def run():
                   -2.5, -1e-4)
     u = jnp.zeros((2, 32))
     b.row("rwkv6_scan_us", fmt(timeit(
-        lambda *a: rwkv6_scan(*a, chunk=32)[0], r, r, r, lw, u), 0))
+        lambda *a: rwkv6_scan(*a, chunk=32, interpret=True)[0],
+        r, r, r, lw, u), 0))
     b.row("rwkv6_ref_us", fmt(timeit(
         lambda *a: R.rwkv6_ref(*a)[0], r, r, r, lw, u), 0))
 
@@ -86,7 +90,8 @@ def run():
     A_log = jnp.zeros((128, 16))
     D = jnp.ones((128,))
     b.row("mamba_scan_us", fmt(timeit(
-        lambda *a: mamba_scan(*a, chunk=32, block_d=128)[0],
+        lambda *a: mamba_scan(*a, chunk=32, block_d=128,
+                              interpret=True)[0],
         x, dt, Bm, Bm, A_log, D), 0))
     b.row("mamba_ref_us", fmt(timeit(
         lambda *a: R.mamba_ref(*a)[0], x, dt, Bm, Bm, A_log, D), 0))

@@ -1,8 +1,8 @@
 """Sharded engine groups benchmark: TP execution through the live stack.
 
-Three experiments on >= 8 host devices (the module re-execs itself with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when the current
-process exposes fewer — so the registry entry works from any parent):
+Three experiments on >= 8 devices. The benchmark runs on the devices
+this process has; on CPU, launch it with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set by the caller:
 
 1. **Group-size sweep**: greedy decode throughput at TP degree 1/2/4 on
    a colocated engine, with byte-identical token parity asserted against
@@ -21,40 +21,21 @@ process exposes fewer — so the registry entry works from any parent):
 from __future__ import annotations
 
 import argparse
-import os
-import subprocess
 import sys
 import time
 
 from benchmarks.common import Bench, fmt
 
 NDEV = 8
-_FLAG = f"--xla_force_host_platform_device_count={NDEV}"
-
-
-def _reexec(smoke: bool) -> int:
-    """Run this module in a child process that sees NDEV host devices."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(root, "src"), root,
-                    env.get("PYTHONPATH", "")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.sharded_engine"]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, env=env, cwd=root)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"sharded_engine child exited {proc.returncode}")
-    return proc.returncode
 
 
 def run(smoke: bool = False, save: bool = True):
     import jax
     if len(jax.devices()) < NDEV:
-        _reexec(smoke)
-        return
+        raise RuntimeError(
+            f"sharded_engine needs {NDEV} devices, this process has "
+            f"{len(jax.devices())}; on CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={NDEV}")
 
     import numpy as np
 

@@ -57,6 +57,28 @@ REGISTRY: Dict[str, HardwareSpec] = {
     h.name: h for h in [H800, H20, TPU_V5E, TPU_V5P, CPU_HOST, SERVERLESS]
 }
 
+# jax ``Device.device_kind`` of each TPU chip in REGISTRY
+TPU_KINDS: Dict[str, str] = {
+    "TPU v5 lite": TPU_V5E.name,
+    "TPU v5": TPU_V5P.name,
+}
+
+
+def device_pool(device) -> str:
+    """REGISTRY name of the hardware a live engine runs on (a jax
+    ``Device``). CPU devices map to the CPU host entry. An accelerator
+    whose kind has no entry raises: pricing it with another chip's peaks
+    would be a silent lie."""
+    if device.platform == "cpu":
+        return CPU_HOST.name
+    name = TPU_KINDS.get(device.device_kind) if device.platform == "tpu" \
+        else None
+    if name is None:
+        raise ValueError(
+            f"no HardwareSpec for {device.platform} device kind "
+            f"{device.device_kind!r}; add it to repro.core.hardware")
+    return name
+
 
 # --- efficiency factors (calibrated against paper Fig. 4; see
 #     benchmarks/calibration.py for the fit) --------------------------------

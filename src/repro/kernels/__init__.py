@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas TPU kernels, each beside its pure-jnp oracle in ref.py. Every
+# kernel takes ``interpret`` as a required keyword: CPU callers pass
+# True (the Pallas interpreter), chip callers False.

@@ -64,7 +64,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def decode_attention(q, k_cache, v_cache, lengths, *,
                      block_k: int = 256, scale: Optional[float] = None,
-                     interpret: bool = True):
+                     interpret: bool):
     """q: [B,H,hd]; caches: [B,kvH,S,hd]; lengths: [B] -> [B,H,hd]."""
     B, H, hd = q.shape
     kvH, S = k_cache.shape[1], k_cache.shape[2]
@@ -156,7 +156,7 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def ragged_paged_decode(q, k_pool, v_pool, tables, lengths, *,
                         scale: Optional[float] = None,
-                        interpret: bool = True):
+                        interpret: bool):
     """Ragged paged decode attention over a shared KV page pool.
 
     q: [B,H,hd] one new token per row; k_pool/v_pool: [N,kvH,page,hd]

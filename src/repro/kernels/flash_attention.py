@@ -72,7 +72,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
                     scale: Optional[float] = None,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: [B,H,S,hd]; k/v: [B,kvH,S,hd] (GQA: H % kvH == 0) -> [B,H,S,hd]."""
     B, H, S, hd = q.shape
     kvH = k.shape[1]

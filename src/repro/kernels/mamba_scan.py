@@ -56,7 +56,7 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, a_log_ref, d_ref, y_ref, h_out_ref,
 
 
 def mamba_scan(x, delta, Bm, Cm, A_log, D, *, chunk: int = 64,
-               block_d: int = 128, interpret: bool = True):
+               block_d: int = 128, interpret: bool):
     """x/delta: [B,S,di]; Bm/Cm: [B,S,ds]; A_log: [di,ds]; D: [di].
 
     Returns (y [B,S,di] fp32, h_out [B,di,ds] fp32)."""

@@ -66,7 +66,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_out_ref, S_ref, *,
         s_out_ref[0] = S_ref[...]
 
 
-def rwkv6_scan(r, k, v, lw, u, *, chunk: int = 32, interpret: bool = True):
+def rwkv6_scan(r, k, v, lw, u, *, chunk: int = 32, interpret: bool):
     """r/k/v: [B,S,H,hd]; lw: [B,S,H,hd] fp32 (clamped log decay);
     u: [H,hd]. Returns (y [B,S,H,hd] fp32, S_out [B,H,hd,hd] fp32)."""
     B, S, H, hd = r.shape

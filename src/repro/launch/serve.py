@@ -1,11 +1,11 @@
 """Serving launcher: ``python -m repro.launch.serve --arch tiny --prompt ...``
 
-Runs the continuous-batching engine on the local device, optionally with two
-affinity-routed pools. With ``--pd-disagg`` the data plane is split into a
-prefill-role engine (compute pool) and a decode-role engine (bandwidth
-pool) with a live KV-cache handoff between them (§6.3). On TPU the same
-serve_step lowers against the production mesh (see launch/dryrun.py for the
-multi-pod proof).
+Runs the continuous-batching engine on the local devices, optionally with
+two affinity-routed pools. With ``--pd-disagg`` the data plane is split
+into a prefill-role engine (compute pool) and a decode-role engine
+(bandwidth pool) with a live KV-cache handoff between them (§6.3).
+``--devices-per-engine`` runs each engine tensor-parallel over a disjoint
+group of this process's devices (on CPU, expose them with ``XLA_FLAGS``).
 """
 from __future__ import annotations
 
@@ -20,6 +20,7 @@ from repro.core import (EngineHandle, LLMProxy, RebalancerConfig,
                         ResourceManager, build_pd_proxy, parse_pools)
 from repro.core.proxy import format_placement_row
 from repro.data.tokenizer import TOKENIZER
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.rl.engine import GenRequest, InferenceEngine
 
@@ -120,6 +121,10 @@ def main(argv=None):
                  "--async-pump (training uses repro.launch.train "
                  "--watchdog)")
 
+    dpe = args.devices_per_engine
+    pre_dpe = args.prefill_devices_per_engine or dpe
+    dec_dpe = args.decode_devices_per_engine or dpe
+    enable_compile_cache(max(dpe, pre_dpe, dec_dpe))
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -131,9 +136,6 @@ def main(argv=None):
         ap.error("--pools only takes effect on the disaggregated plane; "
                  "add --pd-disagg or --affinity")
     rm = ResourceManager(parse_pools(args.pools)) if args.pools else None
-    dpe = args.devices_per_engine
-    pre_dpe = args.prefill_devices_per_engine or dpe
-    dec_dpe = args.decode_devices_per_engine or dpe
     if args.pd_disagg or args.affinity:
         proxy = build_pd_proxy(
             model, params, max_slots=args.slots, max_len=1024,

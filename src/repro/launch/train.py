@@ -1,10 +1,12 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
 Live mode (default): runs the full RollArt agentic-RL pipeline with real
-compute on the local device — use reduced/smoke variants on CPU
-(``--reduced``). With ``--lm`` it runs plain LM pretraining instead.
-On a real TPU slice the same entry point builds the production mesh and
-pjit-shards the train step (``--mesh single|pod2``).
+compute on the local devices (``--reduced`` gives the smoke-scale variant
+of an arch; tests and CPU runs use ``JAX_PLATFORMS=cpu``). With ``--lm``
+it runs plain LM pretraining instead. ``--devices-per-engine`` and
+``--pd-disagg`` place rollout engines on disjoint device groups of this
+process; ``chip_smoke.py`` drives this same plane on a TPU through
+:func:`build_runner`.
 """
 from __future__ import annotations
 
@@ -17,8 +19,10 @@ from repro.configs import get_config
 from repro.core import (DEFAULT_TASKS, EngineHandle, LiveRLRunner, LLMProxy,
                         RebalancerConfig, ResourceManager, RunnerConfig,
                         ServerlessPlatform, build_pd_proxy, parse_pools)
+from repro.core.hardware import device_pool
 from repro.core.proxy import format_placement_row, format_switch_event
 from repro.ft import FTConfig, FTSupervisor, restore_latest
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.rewards.rule_based import REWARD_FNS
 from repro.rl.engine import InferenceEngine
@@ -26,7 +30,7 @@ from repro.rl.trainer import (default_optimizer, init_train_state,
                               make_grpo_train_step, make_lm_train_step)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny")
     ap.add_argument("--reduced", action="store_true",
@@ -123,10 +127,65 @@ def main(argv=None):
                          "through the FT supervisor")
     ap.add_argument("--watchdog-deadline", type=float, default=5.0,
                     metavar="S", help="stall deadline in seconds")
+    return ap
+
+
+def build_runner(args, model, state, step) -> LiveRLRunner:
+    """The live RL plane over ``state``: rollout engines (one local
+    engine, a TP group, or a disaggregated prefill/decode pool) behind an
+    LLMProxy, driven by a LiveRLRunner that trains with ``step``. Also the
+    trainer-restart hook (``restore_latest`` rebuilds the plane through
+    it). Engines without a ResourceManager binding are labelled with the
+    hardware they run on."""
+    pools = parse_pools(args.pools) if args.pools else None
+    pd = args.pd_disagg or args.affinity
+    n_prefill = args.n_prefill or (2 if args.affinity else 1)
+    weights = (tuple(float(w) for w in args.task_weights.split(","))
+               if args.task_weights else None)
+    dpe = args.devices_per_engine
+    local = device_pool(jax.devices()[0])
+    rm = ResourceManager(pools) if pools else None
+    if pd:
+        proxy = build_pd_proxy(
+            model, state.params, max_slots=8, max_len=640,
+            n_prefill=n_prefill, n_decode=args.n_decode,
+            resource_manager=rm,
+            rebalancer=RebalancerConfig() if args.affinity else None,
+            steps_per_dispatch=args.steps_per_dispatch,
+            prefill_devices_per_engine=args.prefill_devices_per_engine or dpe,
+            decode_devices_per_engine=args.decode_devices_per_engine or dpe,
+            prefill_pool=local, decode_pool=local)
+    else:
+        mesh = None
+        if dpe > 1:
+            from repro.launch.mesh import (allocate_engine_devices,
+                                           make_group_mesh)
+            mesh = make_group_mesh(allocate_engine_devices([dpe])[0])
+        eng = InferenceEngine(
+            model, state.params, max_slots=8, max_len=640,
+            steps_per_dispatch=args.steps_per_dispatch, mesh=mesh)
+        proxy = LLMProxy([EngineHandle(eng, local)])
+    return LiveRLRunner(
+        RunnerConfig(batch_size=args.batch, group_size=args.group,
+                     alpha=args.alpha, mode=args.mode,
+                     tasks=tuple(args.tasks.split(",")),
+                     task_weights=weights,
+                     pd_disagg=pd, pools=pools,
+                     affinity=args.affinity,
+                     steps_per_dispatch=args.steps_per_dispatch),
+        proxy, state, step, ServerlessPlatform(),
+        REWARD_FNS[args.reward], seq_len=640)
+
+
+def main(argv=None):
+    ap = build_parser()
     args = ap.parse_args(argv)
     if (args.ckpt_rollouts or args.restore) and not args.ckpt:
         ap.error("--ckpt-rollouts/--restore need --ckpt DIR")
 
+    dpe = args.devices_per_engine
+    enable_compile_cache(max(dpe, args.prefill_devices_per_engine or dpe,
+                             args.decode_devices_per_engine or dpe))
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -149,61 +208,17 @@ def main(argv=None):
         if args.affinity and not args.pools:
             ap.error("--affinity requires --pools "
                      "(e.g. --pools H800:2,H20:2)")
-        pd = args.pd_disagg or args.affinity
-        if args.pools and not pd:
+        if args.pools and not (args.pd_disagg or args.affinity):
             ap.error("--pools only takes effect on the disaggregated "
                      "plane; add --pd-disagg or --affinity")
-        pools = parse_pools(args.pools) if args.pools else None
-        n_prefill = args.n_prefill or (2 if args.affinity else 1)
-        weights = (tuple(float(w) for w in args.task_weights.split(","))
-                   if args.task_weights else None)
-
-        dpe = args.devices_per_engine
-        pre_dpe = args.prefill_devices_per_engine or dpe
-        dec_dpe = args.decode_devices_per_engine or dpe
-
-        def build_runner(st):
-            """Fresh runner over ``st`` — also the trainer-restart hook
-            (``restore_latest`` rebuilds the plane through it)."""
-            rm = ResourceManager(pools) if pools else None
-            if pd:
-                proxy = build_pd_proxy(
-                    model, st.params, max_slots=8, max_len=640,
-                    n_prefill=n_prefill, n_decode=args.n_decode,
-                    resource_manager=rm,
-                    rebalancer=RebalancerConfig() if args.affinity
-                    else None,
-                    steps_per_dispatch=args.steps_per_dispatch,
-                    prefill_devices_per_engine=pre_dpe,
-                    decode_devices_per_engine=dec_dpe)
-            else:
-                mesh = None
-                if dpe > 1:
-                    from repro.launch.mesh import (allocate_engine_devices,
-                                                   make_group_mesh)
-                    mesh = make_group_mesh(
-                        allocate_engine_devices([dpe])[0])
-                eng = InferenceEngine(
-                    model, st.params, max_slots=8, max_len=640,
-                    steps_per_dispatch=args.steps_per_dispatch,
-                    mesh=mesh)
-                proxy = LLMProxy([EngineHandle(eng, "H20")])
-            return LiveRLRunner(
-                RunnerConfig(batch_size=args.batch, group_size=args.group,
-                             alpha=args.alpha, mode=args.mode,
-                             tasks=tuple(args.tasks.split(",")),
-                             task_weights=weights,
-                             pd_disagg=pd, pools=pools,
-                             affinity=args.affinity,
-                             steps_per_dispatch=args.steps_per_dispatch),
-                proxy, st, step, ServerlessPlatform(),
-                REWARD_FNS[args.reward], seq_len=640)
 
         if args.restore:
-            runner, start = restore_latest(args.ckpt, state, build_runner)
+            runner, start = restore_latest(
+                args.ckpt, state,
+                lambda st: build_runner(args, model, st, step))
             print(f"restored paired checkpoint at step {start}")
         else:
-            runner = build_runner(state)
+            runner = build_runner(args, model, state, step)
         # --watchdog needs an FT supervisor to recover through, even
         # without checkpointing/injection configured
         use_ft = (args.ckpt_rollouts or args.failure_rate > 0

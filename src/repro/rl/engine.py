@@ -678,6 +678,10 @@ class InferenceEngine:
                 # the group
                 with self._shard_ctx():
                     params = jax.device_put(params, self._param_shardings)
+            else:
+                # the store hands out host arrays: place them once here,
+                # not on every dispatch that reads them
+                params = jax.device_put(params)
             self.params = params
             self.weight_version = version
             if recompute_caches:

@@ -32,7 +32,8 @@ def test_flash_matches_model_attention():
     kk = jax.random.normal(k(1), (B, kvH, S, hd))
     v = jax.random.normal(k(2), (B, kvH, S, hd))
     model_out = L._attend_causal(q, kk, v, cfg, window=None, q_chunk=64)
-    kern_out = flash_attention(q, kk, v, block_q=64, block_k=64)
+    kern_out = flash_attention(q, kk, v, block_q=64, block_k=64,
+                               interpret=True)
     np.testing.assert_allclose(np.asarray(kern_out), np.asarray(model_out),
                                atol=2e-5, rtol=2e-5)
 
@@ -48,7 +49,8 @@ def test_decode_matches_model_attention_decode():
     kc = jax.random.normal(k(4), (B, kvH, S, hd))
     vc = jax.random.normal(k(5), (B, kvH, S, hd))
     lengths = jnp.full((B,), length)
-    out_k = decode_attention(q, kc, vc, lengths, block_k=64)
+    out_k = decode_attention(q, kc, vc, lengths, block_k=64,
+                             interpret=True)
     # model-side reference: grouped scores + masked softmax (the math inside
     # L.attention_decode after the cache write)
     scores = L._grouped_scores(q[:, :, None, :], kc, cfg)
@@ -72,7 +74,8 @@ def test_rwkv6_kernel_matches_model_chunked_wkv():
     u = jax.random.normal(k(10), (H, hd)) * 0.3
     S0 = jnp.zeros((B, H, hd, hd))
     y_model, S_model = R._chunked_wkv(r, kk, v, lw, u, S0)
-    y_kern, S_kern = rwkv6_scan(r, kk, v, lw, u, chunk=R.RWKV_CHUNK)
+    y_kern, S_kern = rwkv6_scan(r, kk, v, lw, u, chunk=R.RWKV_CHUNK,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(y_kern), np.asarray(y_model),
                                atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(np.asarray(S_kern), np.asarray(S_model),
@@ -102,7 +105,7 @@ def test_mamba_kernel_matches_model_scan():
                                 Bm.astype(jnp.float32),
                                 Cm2.astype(jnp.float32),
                                 p["A_log"], p["D"].astype(jnp.float32),
-                                chunk=32, block_d=64)
+                                chunk=32, block_d=64, interpret=True)
     np.testing.assert_allclose(np.asarray(y_kern), np.asarray(y_model),
                                atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(np.asarray(h_kern), np.asarray(h[:, -1]),

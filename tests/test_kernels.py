@@ -42,7 +42,7 @@ def test_flash_attention(B, H, kvH, S, hd, dtype):
     q = jax.random.normal(k(1), (B, H, S, hd), dtype)
     kk = jax.random.normal(k(2), (B, kvH, S, hd), dtype)
     v = jax.random.normal(k(3), (B, kvH, S, hd), dtype)
-    o = flash_attention(q, kk, v, block_q=64, block_k=64)
+    o = flash_attention(q, kk, v, block_q=64, block_k=64, interpret=True)
     r = R.flash_ref(q, kk, v)
     tol = 5e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(o, np.float32),
@@ -55,7 +55,8 @@ def test_flash_attention_blocks(block_q, block_k):
     q = jax.random.normal(k(4), (B, H, S, hd))
     kk = jax.random.normal(k(5), (B, kvH, S, hd))
     v = jax.random.normal(k(6), (B, kvH, S, hd))
-    o = flash_attention(q, kk, v, block_q=block_q, block_k=block_k)
+    o = flash_attention(q, kk, v, block_q=block_q, block_k=block_k,
+                        interpret=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(R.flash_ref(q, kk, v)),
                                atol=5e-6, rtol=5e-6)
 
@@ -65,7 +66,8 @@ def test_flash_non_causal():
     q = jax.random.normal(k(7), (B, H, S, hd))
     kk = jax.random.normal(k(8), (B, kvH, S, hd))
     v = jax.random.normal(k(9), (B, kvH, S, hd))
-    o = flash_attention(q, kk, v, causal=False, block_q=32, block_k=32)
+    o = flash_attention(q, kk, v, causal=False, block_q=32, block_k=32,
+                        interpret=True)
     r = R.flash_ref(q, kk, v, causal=False)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=5e-6,
                                rtol=5e-6)
@@ -84,7 +86,7 @@ def test_decode_attention(B, H, kvH, S, hd, bk):
     kc = jax.random.normal(k(11), (B, kvH, S, hd))
     vc = jax.random.normal(k(12), (B, kvH, S, hd))
     lengths = jnp.asarray([(S // 2 + 7 * i) % S + 1 for i in range(B)])
-    o = decode_attention(q, kc, vc, lengths, block_k=bk)
+    o = decode_attention(q, kc, vc, lengths, block_k=bk, interpret=True)
     r = R.decode_ref(q, kc, vc, lengths)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=5e-6,
                                rtol=5e-6)
@@ -101,11 +103,12 @@ def test_decode_attention_lengths_property(length_frac, seed):
     vc = jax.random.normal(jax.random.fold_in(kp, 2), (B, kvH, S, hd))
     length = max(1, int(S * length_frac))
     lengths = jnp.asarray([length])
-    o1 = decode_attention(q, kc, vc, lengths, block_k=32)
+    o1 = decode_attention(q, kc, vc, lengths, block_k=32, interpret=True)
     # poison the masked region: output must not change
     poison = kc.at[:, :, length:, :].set(1e6)
     poison_v = vc.at[:, :, length:, :].set(-1e6)
-    o2 = decode_attention(q, poison, poison_v, lengths, block_k=32)
+    o2 = decode_attention(q, poison, poison_v, lengths, block_k=32,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
 
 
@@ -124,7 +127,7 @@ def test_rwkv6_scan(B, S, H, hd, chunk):
     lw = jnp.clip(-jnp.exp(jax.random.normal(k(23), (B, S, H, hd))),
                   -2.5, -1e-4)
     u = jax.random.normal(k(24), (H, hd)) * 0.5
-    y, S_out = rwkv6_scan(r, kk, v, lw, u, chunk=chunk)
+    y, S_out = rwkv6_scan(r, kk, v, lw, u, chunk=chunk, interpret=True)
     yr, S_ref = R.rwkv6_ref(r, kk, v, lw, u)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=3e-4,
                                rtol=3e-4)
@@ -146,7 +149,7 @@ def test_rwkv6_chunk_invariance(seed, chunk):
     lw = jnp.clip(-jnp.exp(jax.random.normal(jax.random.fold_in(kp, 3),
                                              (B, S, H, hd))), -2.5, -1e-4)
     u = jnp.zeros((H, hd))
-    y1, s1 = rwkv6_scan(r, kk, v, lw, u, chunk=chunk)
+    y1, s1 = rwkv6_scan(r, kk, v, lw, u, chunk=chunk, interpret=True)
     y2, s2 = R.rwkv6_ref(r, kk, v, lw, u)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=5e-4,
                                rtol=5e-4)
@@ -158,7 +161,8 @@ def test_rwkv6_rejects_unstable_chunk():
     B, S, H, hd = 1, 64, 1, 16
     z = jnp.zeros((B, S, H, hd))
     with pytest.raises(AssertionError):
-        rwkv6_scan(z, z, z, z - 1.0, jnp.zeros((H, hd)), chunk=64)
+        rwkv6_scan(z, z, z, z - 1.0, jnp.zeros((H, hd)), chunk=64,
+                   interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +180,8 @@ def test_mamba_scan(B, S, di, ds, chunk, bd):
     Cm = jax.random.normal(k(33), (B, S, ds))
     A_log = jax.random.normal(k(34), (di, ds)) * 0.5
     D = jax.random.normal(k(35), (di,))
-    y, h = mamba_scan(x, delta, Bm, Cm, A_log, D, chunk=chunk, block_d=bd)
+    y, h = mamba_scan(x, delta, Bm, Cm, A_log, D, chunk=chunk, block_d=bd,
+                      interpret=True)
     yr, hr = R.mamba_ref(x, delta, Bm, Cm, A_log, D)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-5,
                                rtol=1e-5)
@@ -199,10 +204,11 @@ def test_mamba_state_continuation_property(seed):
     A_log = jax.random.normal(jax.random.fold_in(kp, 4), (di, ds)) * 0.3
     D = jnp.zeros((di,))
     y_full, h_full = mamba_scan(x, delta, Bm, Cm, A_log, D, chunk=16,
-                                block_d=32)
+                                block_d=32, interpret=True)
     half = S // 2
     _, h1 = mamba_scan(x[:, :half], delta[:, :half], Bm[:, :half],
-                       Cm[:, :half], A_log, D, chunk=16, block_d=32)
+                       Cm[:, :half], A_log, D, chunk=16, block_d=32,
+                       interpret=True)
     y2, h2 = R.mamba_ref(x[:, half:], delta[:, half:], Bm[:, half:],
                          Cm[:, half:], A_log, D, h0=h1)
     np.testing.assert_allclose(np.asarray(y_full[:, half:]), np.asarray(y2),

@@ -278,7 +278,13 @@ def test_midflight_weight_sync_parity(tiny):
         return eng.pop_result("r")
 
     d, p = sync_run(False), sync_run(True)
-    assert p.tokens == d.tokens and p.logprobs == d.logprobs
+    assert p.tokens == d.tokens
+    # one request in two slots: the dense decode dispatch runs batch 2
+    # (max_slots) while the paged one compacts to batch 1, so the float32
+    # matmul reductions differ in order and the logprobs differ in the
+    # last bits (~1e-6 at |lp|~3) from the first decode step on, before
+    # and after the sync alike; the token streams must still match
+    np.testing.assert_allclose(p.logprobs, d.logprobs, rtol=1e-5, atol=1e-5)
 
 
 def test_incremental_capture_shrinks_when_idle(tiny):
@@ -409,7 +415,8 @@ def test_ragged_paged_decode_matches_ref(batch, zero_rows):
     tables = jnp.arange(batch * P, dtype=jnp.int32).reshape(batch, P)
     lens = jax.random.randint(kl, (batch,), 1, P * page + 1)
     lens = lens.at[:min(zero_rows, batch)].set(0)    # inactive rows
-    out = ragged_paged_decode(q, pool_k, pool_v, tables, lens)
+    out = ragged_paged_decode(q, pool_k, pool_v, tables, lens,
+                              interpret=True)
     gk = jnp.moveaxis(pool_k[tables], 2, 1).reshape(batch, kvH, P * page, hd)
     gv = jnp.moveaxis(pool_v[tables], 2, 1).reshape(batch, kvH, P * page, hd)
     want = np.asarray(R.decode_ref(q, gk, gv, lens))
