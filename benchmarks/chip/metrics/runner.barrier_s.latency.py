@@ -1,0 +1,5 @@
+"""``runner.barrier_s``, in the cells where it moves the trajectory latency
+rather than the step time or the token rate."""
+from benchmarks.chip import spec
+
+read = spec.reader("runner.barrier_s")
