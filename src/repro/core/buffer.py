@@ -48,6 +48,8 @@ class SampleBuffer:
         # stats
         self.total_put = 0                         # guarded by: _lock
         self.total_evicted = 0                     # guarded by: _lock
+        # policy tokens of the evicted trajectories (rollout waste)
+        self.total_evicted_tokens = 0              # guarded by: _lock
         self.total_consumed = 0                    # guarded by: _lock
         self.total_deduped = 0                     # guarded by: _lock
 
@@ -77,6 +79,7 @@ class SampleBuffer:
     def _evict(self, traj: Trajectory):   # requires: _lock
         self._buffered.discard(traj.traj_id)
         self.total_evicted += 1
+        self.total_evicted_tokens += sum(traj.loss_mask)
         if self.on_evict:
             self.on_evict(traj)
 
@@ -107,6 +110,7 @@ class SampleBuffer:
                     "current_version": self.current_version,
                     "total_put": self.total_put,
                     "total_evicted": self.total_evicted,
+                    "total_evicted_tokens": self.total_evicted_tokens,
                     "total_consumed": self.total_consumed,
                     "total_deduped": self.total_deduped}
 

@@ -15,6 +15,7 @@ from repro.core.proxy import LLMProxy
 from repro.data.pipeline import Trajectory
 from repro.data.tokenizer import ByteTokenizer
 from repro.envs.base import EnvError, TextEnv
+from repro.obs import spans
 from repro.rl.engine import GenRequest, GenResult
 
 _ids = itertools.count()
@@ -82,6 +83,9 @@ class EnvManager:
         self.start_version = 0
         self.end_version = 0
         self.env_return = 0.0
+        # tokens the aborted in-flight request had generated, which the
+        # trajectory never took in (rollout waste accounting)
+        self.dropped_tokens = 0
         self._req_counter = itertools.count()
         self._active_req: Optional[str] = None
 
@@ -127,6 +131,7 @@ class EnvManager:
             return
         if result.finish_reason == "aborted":
             self.state = EMState.ABORTED
+            self.dropped_tokens = len(result.tokens)
             if self.on_complete:
                 self.on_complete(self)
             return
@@ -139,7 +144,8 @@ class EnvManager:
         action = self.tok.decode(action_ids)
         self.turns += 1
         try:
-            obs, reward, done, _ = self.env.step(action)
+            with spans.span("repro.env.step"):
+                obs, reward, done, _ = self.env.step(action)
         except EnvError:
             self.state = EMState.FAILED
             if self.on_complete:

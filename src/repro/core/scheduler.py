@@ -84,6 +84,7 @@ from repro.core.weightstore import (MooncakeStore, pull_param_chunks,
 from repro.data.pipeline import Trajectory, TaskSampler, pack_batch
 from repro.data.tokenizer import ByteTokenizer
 from repro.envs import make_env
+from repro.obs import spans
 from repro.rl.trainer import TrainState
 from repro.serve.service import RolloutJob, RolloutService
 
@@ -174,13 +175,26 @@ class StepMetrics:
     #                                  buffer's traj_id dedup (delta; > 0
     #                                  only after a rollout-plane restore)
     fetch_s: float = 0.0             # step (1): blocking batch retrieval
-    barrier_s: float = 0.0           # steps (2)-(5): push-await + suspend/
-    #                                  update/resume critical section
+    barrier_s: float = 0.0           # steps (2)-(5): push_wait_s +
+    #                                  lock_wait_s + sync_s
     train_s: float = 0.0             # step (6): the GRPO update itself
     staleness: int = 0               # weight-version staleness of the
     #                                  trained batch: trainer version at
     #                                  fetch minus the OLDEST start_version
     #                                  in the batch (worst case)
+    push_wait_s: float = 0.0         # barrier: last step's push awaited
+    lock_wait_s: float = 0.0         # barrier: waiting for the service lock
+    sync_s: float = 0.0              # barrier, under the lock: suspend,
+    #                                  pull, update with KV recompute,
+    #                                  resume, barrier hook
+    rollout_host_s: float = 0.0      # seconds the service ticks held its
+    #                                  lock not waiting on the chip (delta
+    #                                  of the service's tick_host_s)
+    aborted_tokens: int = 0          # policy tokens of trajectories aborted
+    #                                  before scoring, the in-flight
+    #                                  request's included (delta)
+    evicted_tokens: int = 0          # policy tokens of trajectories the
+    #                                  buffer evicted as stale (delta)
 
     def to_dict(self) -> Dict[str, float]:
         """Stable flat schema — key order and types are
@@ -211,6 +225,12 @@ STEP_METRICS_SCHEMA = (
     ("staleness", int),
     ("role_switches", int),
     ("deduped", int),
+    ("push_wait_s", float),
+    ("lock_wait_s", float),
+    ("sync_s", float),
+    ("rollout_host_s", float),
+    ("aborted_tokens", int),
+    ("evicted_tokens", int),
 )
 
 
@@ -315,6 +335,9 @@ class LiveRLRunner:
         self._last_aborted = 0
         self._last_role_switches = 0
         self._last_deduped = 0
+        self._last_aborted_tokens = 0
+        self._last_evicted_tokens = 0
+        self._last_tick_host_s = 0.0
         # weight-sync format: a plane with TP engine groups publishes
         # PER-SHARD chunks (engines assemble their own shards and never
         # materialize a full per-engine copy); a single-device plane
@@ -509,10 +532,12 @@ class LiveRLRunner:
         chunks when any engine runs a TP group, dense otherwise. The FT
         restore path republishes through this too, so a restored plane
         keeps pulling the format its engines expect."""
-        if self._tp_chunks > 1:
-            return push_params_sharded(self.store, params, version,
-                                       self._tp_chunks, self._chunk_dims)
-        return push_params(self.store, params, version)
+        with spans.span("repro.weights.push"):
+            if self._tp_chunks > 1:
+                return push_params_sharded(self.store, params, version,
+                                           self._tp_chunks,
+                                           self._chunk_dims)
+            return push_params(self.store, params, version)
 
     def _push_async(self):
         """Publish the new weights off-thread; the transfer overlaps the
@@ -544,106 +569,126 @@ class LiveRLRunner:
             self._start_rollout_worker()
         try:
             for _ in range(num_steps):
-                step = len(self.history)
-                t0 = time.monotonic()
-                # (1) get_batch. one_off trains on the PREVIOUS iteration's
-                # batch (fetched at the end of the last step, so it was in
-                # hand before this step began) while its successor rolls out.
-                if one_off:
-                    if self._prev_batch is None:
-                        self._prev_batch = self._await_batch()   # priming
-                        self._prev_batch_fetched_step = -1
-                    batch_trajs = self._prev_batch
-                    fetched_step = self._prev_batch_fetched_step
-                else:
-                    batch_trajs = self._await_batch()
-                    fetched_step = step
-                t_fetch = time.monotonic()
-                self.last_batch = batch_trajs
-                staleness = self.version - min(t.start_version
-                                               for t in batch_trajs)
-                # (2)-(5) the ONLY rollout/trainer barrier: suspend,
-                # pull + update + in-flight KV recompute, resume — atomic
-                # w.r.t. the service tick so a weight swap never races a
-                # decode.
-                self._await_push()
-                with self.service.barrier():
-                    self.proxy.suspend()
-                    # (5) recomp happens inside update_all[_chunks]
-                    # (no-op for engines already at version v)
-                    if self._tp_chunks > 1:
-                        pulled = pull_param_chunks(self.store,
-                                                   self.state.params)
-                        if pulled is not None:
-                            chunks, v = pulled
-                            self.proxy.update_all_chunks(
-                                chunks, v, recompute_caches=True)
-                    else:
-                        pulled = pull_params(self.store, self.state.params)
-                        if pulled is not None:
-                            params, v = pulled
-                            self.proxy.update_all(params, v,
-                                                  recompute_caches=True)
-                    self.proxy.resume()
-                    if self.barrier_hook is not None:
-                        # rollout snapshot point: the service lock is
-                        # held, so every engine slot / env manager /
-                        # pending reward is quiescent and mutually
-                        # consistent
-                        self.barrier_hook(self, step)
-                t_barrier = time.monotonic()
-                # (6) train_step, overlapped with the resumed rollout
-                batch = self._pack(batch_trajs)
-                d0 = self._decode_tokens_total()
-                self.state, metrics = self.train_step_fn(self.state, batch)
-                loss = float(metrics["loss"])   # blocks until step done
-                t_train = time.monotonic()
-                d1 = self._decode_tokens_total()
-                self.version = int(self.state.version)
-                self.buffer.set_version(self.version)
-                if self.profiler is not None:
-                    with self.service.barrier():    # §9 online re-routing
-                        self.profiler.apply_to(self.proxy)
-                self._push_async()
-                if one_off:
-                    # the batch produced while we trained becomes the NEXT
-                    # iteration's training data
-                    self._prev_batch = self._await_batch()
-                    self._prev_batch_fetched_step = step
-                if sync_like:
-                    self._drain_rollout()
-                rewards = [t.reward for t in batch_trajs]
-                ev_total = self.buffer.total_evicted
-                ab_total = self.proxy.aborted
-                rs_total = self.proxy.role_switches
-                dd_total = self.buffer.total_deduped
-                sm = StepMetrics(
-                    step=step, wall_s=time.monotonic() - t0,
-                    loss=loss,
-                    reward_mean=float(np.mean(rewards)),
-                    evicted=ev_total - self._last_evicted,
-                    aborted=ab_total - self._last_aborted,
-                    trajs=len(batch_trajs),
-                    decode_during_train=d1 - d0,
-                    batch_fetched_step=fetched_step,
-                    batch_max_version=max(t.start_version
-                                          for t in batch_trajs),
-                    role_switches=rs_total - self._last_role_switches,
-                    deduped=dd_total - self._last_deduped,
-                    fetch_s=t_fetch - t0,
-                    barrier_s=t_barrier - t_fetch,
-                    train_s=t_train - t_barrier,
-                    staleness=staleness)
-                self._last_evicted, self._last_aborted = ev_total, ab_total
-                self._last_role_switches = rs_total
-                self._last_deduped = dd_total
-                self.trained_log.append([t.traj_id for t in batch_trajs])
+                with spans.phase("repro.runner.step") as whole:
+                    sm = self._step(sync_like, one_off)
+                sm.wall_s = whole.seconds
                 self.history.append(sm)
         finally:
             if self.threaded:
                 self._pause_rollout_worker()
             self._await_push()
         return self.history
+
+    def _step(self, sync_like: bool, one_off: bool) -> StepMetrics:
+        """One pass of the six-step protocol; the caller times the whole
+        (``wall_s``) and records it."""
+        step = len(self.history)
+        # (1) get_batch. one_off trains on the PREVIOUS iteration's batch
+        # (fetched at the end of the last step, so it was in hand before
+        # this step began) while its successor rolls out.
+        with spans.phase("repro.runner.fetch") as fetch:
+            if one_off:
+                if self._prev_batch is None:
+                    self._prev_batch = self._await_batch()   # priming
+                    self._prev_batch_fetched_step = -1
+                batch_trajs = self._prev_batch
+                fetched_step = self._prev_batch_fetched_step
+            else:
+                batch_trajs = self._await_batch()
+                fetched_step = step
+        self.last_batch = batch_trajs
+        staleness = self.version - min(t.start_version
+                                       for t in batch_trajs)
+        # (2)-(5) the ONLY rollout/trainer barrier: suspend, pull +
+        # update + in-flight KV recompute, resume — atomic w.r.t. the
+        # service tick so a weight swap never races a decode.
+        with spans.phase("repro.runner.push_wait") as push:
+            self._await_push()
+        with self.service.barrier():
+            lock_wait_s = self.service.last_barrier_wait_s
+            with spans.phase("repro.runner.sync") as sync:
+                self.proxy.suspend()
+                # (5) recomp happens inside update_all[_chunks]
+                # (no-op for engines already at version v)
+                if self._tp_chunks > 1:
+                    pulled = pull_param_chunks(self.store,
+                                               self.state.params)
+                    if pulled is not None:
+                        chunks, v = pulled
+                        self.proxy.update_all_chunks(
+                            chunks, v, recompute_caches=True)
+                else:
+                    pulled = pull_params(self.store, self.state.params)
+                    if pulled is not None:
+                        params, v = pulled
+                        self.proxy.update_all(params, v,
+                                              recompute_caches=True)
+                self.proxy.resume()
+                if self.barrier_hook is not None:
+                    # rollout snapshot point: the service lock is held,
+                    # so every engine slot / env manager / pending
+                    # reward is quiescent and mutually consistent
+                    self.barrier_hook(self, step)
+        t_barrier = time.monotonic()
+        # (6) train_step, overlapped with the resumed rollout
+        with spans.phase("repro.runner.train") as train:
+            with spans.span("repro.runner.pack"):
+                batch = self._pack(batch_trajs)
+            d0 = self._decode_tokens_total()
+            self.state, metrics = self.train_step_fn(self.state, batch)
+            loss = float(metrics["loss"])   # blocks until step done
+        d1 = self._decode_tokens_total()
+        self.version = int(self.state.version)
+        self.buffer.set_version(self.version)
+        if self.profiler is not None:
+            with self.service.barrier():    # §9 online re-routing
+                self.profiler.apply_to(self.proxy)
+        self._push_async()
+        if one_off:
+            # the batch produced while we trained becomes the NEXT
+            # iteration's training data
+            self._prev_batch = self._await_batch()
+            self._prev_batch_fetched_step = step
+        if sync_like:
+            self._drain_rollout()
+        rewards = [t.reward for t in batch_trajs]
+        ev_total = self.buffer.total_evicted
+        ab_total = self.proxy.aborted
+        rs_total = self.proxy.role_switches
+        dd_total = self.buffer.total_deduped
+        ab_tok_total = self._tenant.stats["aborted_tokens"]
+        ev_tok_total = self.buffer.total_evicted_tokens
+        host_total = self.service.tick_host_s
+        sm = StepMetrics(
+            step=step, wall_s=0.0,
+            loss=loss,
+            reward_mean=float(np.mean(rewards)),
+            evicted=ev_total - self._last_evicted,
+            aborted=ab_total - self._last_aborted,
+            trajs=len(batch_trajs),
+            decode_during_train=d1 - d0,
+            batch_fetched_step=fetched_step,
+            batch_max_version=max(t.start_version for t in batch_trajs),
+            role_switches=rs_total - self._last_role_switches,
+            deduped=dd_total - self._last_deduped,
+            fetch_s=fetch.seconds,
+            barrier_s=t_barrier - fetch.t1,
+            train_s=train.seconds,
+            staleness=staleness,
+            push_wait_s=push.seconds,
+            lock_wait_s=lock_wait_s,
+            sync_s=sync.seconds,
+            rollout_host_s=host_total - self._last_tick_host_s,
+            aborted_tokens=ab_tok_total - self._last_aborted_tokens,
+            evicted_tokens=ev_tok_total - self._last_evicted_tokens)
+        self._last_evicted, self._last_aborted = ev_total, ab_total
+        self._last_role_switches = rs_total
+        self._last_deduped = dd_total
+        self._last_aborted_tokens = ab_tok_total
+        self._last_evicted_tokens = ev_tok_total
+        self._last_tick_host_s = host_total
+        self.trained_log.append([t.traj_id for t in batch_trajs])
+        return sm
 
     def _pack(self, trajs: List[Trajectory]) -> Dict:
         import jax.numpy as jnp

@@ -42,6 +42,8 @@ ENGINE_COUNTERS = (
     ("shared_prefix_tokens", "prefill tokens served from shared prefix"),
     ("prefix_hits", "prefix-cache hits"),
     ("prefix_misses", "prefix-cache misses"),
+    ("device_wait_s", "seconds from dispatch to readback of prefills and "
+                      "decode macro-steps"),
 )
 ENGINE_GAUGES = (
     ("weight_version", "weight version currently loaded"),
@@ -62,7 +64,7 @@ PROXY_COUNTERS = (
 )
 TENANT_COUNTERS = ("submitted", "rejected", "admitted", "completed",
                    "aborted", "failed", "scored", "stream_tokens",
-                   "tokens_out", "reward_retries")
+                   "tokens_out", "reward_retries", "aborted_tokens")
 TENANT_GAUGES = ("inflight", "queued", "active_ems", "pending_rewards",
                  "vtime")
 
@@ -132,6 +134,9 @@ def instrument_buffer(reg: MetricsRegistry, buffer) -> None:
         "total_deduped": reg.counter("repro_buffer_deduped_total",
                                      "replayed trajectories dropped by "
                                      "traj_id dedup"),
+        "total_evicted_tokens": reg.counter(
+            "repro_buffer_evicted_tokens_total",
+            "policy tokens of trajectories evicted as stale"),
     }
 
     def collect():
@@ -176,7 +181,8 @@ def instrument_serverless(reg: MetricsRegistry, sls) -> None:
 
 def instrument_service(reg: MetricsRegistry, svc) -> None:
     """Per-tenant admission/QoS counters and occupancy gauges (labels:
-    tenant) plus the service beat."""
+    tenant), the service beat, and the service lock's seconds: held in
+    ticks, waited for in weight-sync barriers."""
     cnt = {k: reg.counter(f"repro_service_{k}_total",
                           f"tenant {k} events", ("tenant",))
            for k in TENANT_COUNTERS}
@@ -185,9 +191,20 @@ def instrument_service(reg: MetricsRegistry, svc) -> None:
            for k in TENANT_GAUGES}
     beats = reg.gauge("repro_service_beats",
                       "pump-loop liveness beat (bumped after every tick)")
+    lock = {"tick_s": reg.counter("repro_service_tick_seconds_total",
+                                  "seconds holding the service lock in "
+                                  "ticks and sync drains"),
+            "tick_host_s": reg.counter(
+                "repro_service_tick_host_seconds_total",
+                "tick seconds not waiting on the engines' dispatches"),
+            "barrier_wait_s": reg.counter(
+                "repro_service_barrier_wait_seconds_total",
+                "seconds waiting for the service lock in barriers")}
 
     def collect():
         beats.child().set(svc.beats)
+        for k, v in svc.timings().items():
+            lock[k].child().set_total(v)
         for name, row in svc.stats().items():
             for k, fam in cnt.items():
                 fam.labels(tenant=name).set_total(row[k])

@@ -76,6 +76,7 @@ import numpy as np
 from repro.distributed.sharding import (SERVE_RULES, axis_rules,
                                         param_sharding, validate_group)
 from repro.models.model import Model
+from repro.obs import spans
 from repro.rl.paged_kv import PagedKVAllocator, PrefixCache
 from repro.rl.sampling import sample_mixed
 
@@ -361,6 +362,10 @@ class InferenceEngine:
         self.handoffs_out = 0                      # guarded by: _step_lock
         self.handoffs_in = 0                       # guarded by: _step_lock
         self.crashes = 0                           # guarded by: _step_lock
+        # seconds from dispatch to the end of the readback of admission
+        # prefills and decode macro-steps (the repro.engine.prefill /
+        # .decode spans): the pumping thread waiting on the chip
+        self.device_wait_s = 0.0                   # guarded by: _step_lock
         # liveness beat for the observability watchdog: bumped at the END
         # of every step() AFTER _step_lock is released — a bare lock-free
         # counter (atomic under the GIL) so the watchdog can read it
@@ -671,17 +676,19 @@ class InferenceEngine:
         with self._step_lock:
             if version == self.weight_version:
                 return
-            if self.mesh is not None:
-                # per-leaf sharded placement: each leaf lands under its
-                # NamedSharding (device_put splits host leaves into
-                # shards), never as a whole-array copy on one device of
-                # the group
-                with self._shard_ctx():
-                    params = jax.device_put(params, self._param_shardings)
-            else:
-                # the store hands out host arrays: place them once here,
-                # not on every dispatch that reads them
-                params = jax.device_put(params)
+            with spans.span("repro.weights.pull"):
+                if self.mesh is not None:
+                    # per-leaf sharded placement: each leaf lands under
+                    # its NamedSharding (device_put splits host leaves
+                    # into shards), never as a whole-array copy on one
+                    # device of the group
+                    with self._shard_ctx():
+                        params = jax.device_put(params,
+                                                self._param_shardings)
+                else:
+                    # the store hands out host arrays: place them once
+                    # here, not on every dispatch that reads them
+                    params = jax.device_put(params)
             self.params = params
             self.weight_version = version
             if recompute_caches:
@@ -690,9 +697,7 @@ class InferenceEngine:
                     # weights: a post-sync fork of it would silently mix
                     # versions in one trajectory
                     self._prefix.clear()
-                for i, s in enumerate(self._slots):
-                    if s.active and s.pos > 0:
-                        self._reprefill_slot(i)
+                self._recompute_active()
 
     def update_from_chunks(self, chunks, version: int,
                            recompute_caches: bool = True):
@@ -714,17 +719,24 @@ class InferenceEngine:
             shardings = (jax.tree.leaves(self._param_shardings)
                          if self.mesh is not None
                          else [None] * len(chunks))
-            leaves = [self._assemble_leaf(dim, parts, shd)
-                      for (dim, parts), shd in zip(chunks, shardings)]
+            with spans.span("repro.weights.pull"):
+                leaves = [self._assemble_leaf(dim, parts, shd)
+                          for (dim, parts), shd in zip(chunks, shardings)]
             self.params = jax.tree.unflatten(treedef, leaves)
             self.weight_version = version
             if recompute_caches:
                 if self.paged:
                     # stale-version prefix KV, same as update_params
                     self._prefix.clear()
-                for i, s in enumerate(self._slots):
-                    if s.active and s.pos > 0:
-                        self._reprefill_slot(i)
+                self._recompute_active()
+
+    def _recompute_active(self):   # requires: _step_lock
+        """Protocol step (5): rebuild every in-flight slot's cache under
+        the weights just installed."""
+        with spans.span("repro.engine.recompute"):
+            for i, s in enumerate(self._slots):
+                if s.active and s.pos > 0:
+                    self._reprefill_slot(i)
 
     def _assemble_leaf(self, dim, parts, sharding):   # requires: _step_lock
         """One param leaf from its host chunks. ``sync_bytes`` counts the
@@ -885,14 +897,18 @@ class InferenceEngine:
         s.pos = len(req.prompt)
         s.start_version = self.weight_version
         self._grow_stop_width(req.stop_tokens)
-        tok, lp = self._prefill_slot(i, req.temperature, ctx_tokens=shared)
+        with spans.phase("repro.engine.prefill") as ph:
+            tok, lp = self._prefill_slot(i, req.temperature,
+                                         ctx_tokens=shared)
+            tok, lp = int(tok[0]), float(lp[0])
+        self.device_wait_s += ph.seconds
         self.prefill_tokens += s.pos - shared   # real NEW tokens prefilled
         if self.paged:
             self.shared_prefix_tokens += shared
             # register the freshly-prefilled prompt pages so concurrent
             # admissions of shared-prompt requests fork them immediately
             self._prefix.insert(req.prompt, self._tables[i])
-        self._append_token(i, int(tok[0]), float(lp[0]))
+        self._append_token(i, tok, lp)
         # stream the first sampled token (idempotent if _append_token
         # already finished the request and _finish emitted it)
         self._emit_progress(req.request_id, s)
@@ -1273,44 +1289,53 @@ class InferenceEngine:
             self._gather_slot_arrays()
         if K == 1:
             # legacy single-step dispatch (stop/length handled host-side)
-            with self._shard_ctx():
-                toks, lps, self._cache = self._decode_jit(
-                    self.params, jnp.asarray(last_tokens), self._cache,
-                    jnp.asarray(positions), self._next_key(),
-                    jnp.asarray(temps))
-            self.decode_dispatches += 1
-            toks, lps = np.asarray(toks), np.asarray(lps)
-            for i in active:
-                if self._slots[i].active:
-                    self.decode_tokens += 1
-                    self._append_token(i, int(toks[i]), float(lps[i]))
-            self._emit_step_progress(active)
+            with spans.phase("repro.engine.decode") as ph:
+                with self._shard_ctx():
+                    toks, lps, self._cache = self._decode_jit(
+                        self.params, jnp.asarray(last_tokens), self._cache,
+                        jnp.asarray(positions), self._next_key(),
+                        jnp.asarray(temps))
+                self.decode_dispatches += 1
+                toks, lps = np.asarray(toks), np.asarray(lps)
+            self.device_wait_s += ph.seconds
+            with spans.span("repro.engine.emit"):
+                for i in active:
+                    if self._slots[i].active:
+                        self.decode_tokens += 1
+                        self._append_token(i, int(toks[i]), float(lps[i]))
+                self._emit_step_progress(active)
             return len(active)
         # device-resident block: the jit consumes one key per inner step
         # (the SAME split-chain schedule as K single-step dispatches, so
         # sampled streams are reproducible across steps_per_dispatch
         # settings) and hands back the advanced chain head
-        with self._shard_ctx():
-            toks, lps, emitted, self._cache, self._key = \
-                self._decode_block_jit(
-                    self.params, jnp.asarray(last_tokens), self._cache,
-                    jnp.asarray(positions), self._key, jnp.asarray(temps),
-                    jnp.asarray(stop_ids), jnp.asarray(budgets))
-        self.decode_dispatches += 1
-        toks = np.asarray(toks)          # [K, B]
-        lps = np.asarray(lps)
-        emitted = np.asarray(emitted)
+        with spans.phase("repro.engine.decode") as ph:
+            with self._shard_ctx():
+                toks, lps, emitted, self._cache, self._key = \
+                    self._decode_block_jit(
+                        self.params, jnp.asarray(last_tokens), self._cache,
+                        jnp.asarray(positions), self._key,
+                        jnp.asarray(temps), jnp.asarray(stop_ids),
+                        jnp.asarray(budgets))
+            self.decode_dispatches += 1
+            toks = np.asarray(toks)          # [K, B]
+            lps = np.asarray(lps)
+            emitted = np.asarray(emitted)
+        self.device_wait_s += ph.seconds
         n_emitted = 0
-        for i in active:
-            # each slot's emitted column is a True-prefix; _append_token
-            # re-derives the stop/length finish the device masked on
-            for k in range(K):
-                if not self._slots[i].active or not emitted[k, i]:
-                    break
-                self.decode_tokens += 1
-                n_emitted += 1
-                self._append_token(i, int(toks[k, i]), float(lps[k, i]))
-        self._emit_step_progress(active)
+        with spans.span("repro.engine.emit"):
+            for i in active:
+                # each slot's emitted column is a True-prefix;
+                # _append_token re-derives the stop/length finish the
+                # device masked on
+                for k in range(K):
+                    if not self._slots[i].active or not emitted[k, i]:
+                        break
+                    self.decode_tokens += 1
+                    n_emitted += 1
+                    self._append_token(i, int(toks[k, i]),
+                                       float(lps[k, i]))
+            self._emit_step_progress(active)
         return n_emitted
 
     def _decode_macro_paged(self, active: List[int]) -> int:   # requires: _step_lock
@@ -1351,30 +1376,34 @@ class InferenceEngine:
             hi = min((s.pos - 1 + K) // self.page_size + 1, len(tbl))
             self._dirty.update(tbl[lo:hi])
             tables[j] = self._full_table(i)
-        with self._shard_ctx():
-            toks, lps, emitted, self._pool, self._key = \
-                self._decode_block_paged_jit(
-                    self.params, jnp.asarray(last_tokens), self._pool,
-                    jnp.asarray(tables), jnp.asarray(positions),
-                    self._key, jnp.asarray(temps), jnp.asarray(stop_ids),
-                    jnp.asarray(budgets))
-        self.decode_dispatches += 1
-        toks = np.asarray(toks)          # [K, ba]
-        lps = np.asarray(lps)
-        emitted = np.asarray(emitted)
+        with spans.phase("repro.engine.decode") as ph:
+            with self._shard_ctx():
+                toks, lps, emitted, self._pool, self._key = \
+                    self._decode_block_paged_jit(
+                        self.params, jnp.asarray(last_tokens), self._pool,
+                        jnp.asarray(tables), jnp.asarray(positions),
+                        self._key, jnp.asarray(temps),
+                        jnp.asarray(stop_ids), jnp.asarray(budgets))
+            self.decode_dispatches += 1
+            toks = np.asarray(toks)          # [K, ba]
+            lps = np.asarray(lps)
+            emitted = np.asarray(emitted)
+        self.device_wait_s += ph.seconds
         n_emitted = 0
-        for j, i in enumerate(active):
-            for k in range(K):
-                if not self._slots[i].active or not emitted[k, j]:
-                    break
-                self.decode_tokens += 1
-                n_emitted += 1
-                self._append_token(i, int(toks[k, j]), float(lps[k, j]))
-        self._emit_step_progress(active)
+        with spans.span("repro.engine.emit"):
+            for j, i in enumerate(active):
+                for k in range(K):
+                    if not self._slots[i].active or not emitted[k, j]:
+                        break
+                    self.decode_tokens += 1
+                    n_emitted += 1
+                    self._append_token(i, int(toks[k, j]),
+                                       float(lps[k, j]))
+            self._emit_step_progress(active)
         return n_emitted
 
     # ------------------------------------------------------------------
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, float]:
         """Consistent snapshot of the step-granular counters. Callers
         must NOT hold any proxy/runner lock here (it takes ``_step_lock``,
         and the engine calls back into those holders' locks from under
@@ -1394,6 +1423,7 @@ class InferenceEngine:
                 "tp_group": self.tp_group,
                 "sharding_drops": self.sharding_drops,
                 "sync_bytes": self.sync_bytes,
+                "device_wait_s": self.device_wait_s,
             }
             with self._lock:   # nested acquisition: canonical order
                 out["rejected_too_long"] = self.rejected_too_long

@@ -63,6 +63,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.core.envmanager import EMState, EnvManager, RolloutPolicy
 from repro.core.proxy import LLMProxy
+from repro.obs import spans
 from repro.rl.engine import GenRequest, GenResult
 from repro.serve.stream import TokenStream
 
@@ -173,7 +174,7 @@ class Tenant:
     stats: Dict[str, int] = field(default_factory=lambda: collections.Counter(
         submitted=0, rejected=0, admitted=0, completed=0, aborted=0,
         failed=0, scored=0, stream_tokens=0, tokens_out=0,
-        reward_retries=0))
+        reward_retries=0, aborted_tokens=0))
 
 
 class RolloutService:
@@ -207,6 +208,15 @@ class RolloutService:
         self._completed_lock = threading.Lock()
         self._tenants: Dict[str, Tenant] = {}      # guarded by: _lock
         self._job_counter = itertools.count()      # guarded by: _lock
+        # cumulative seconds holding the lock in ticks and sync drains
+        # (``repro.service.tick``), the part of them not spent waiting on
+        # the engines' dispatches (their ``device_wait_s``), and waiting
+        # for the lock in barriers (``repro.runner.lock_wait``); the last
+        # barrier's wait is read by its caller while it holds the lock
+        self.tick_s = 0.0                          # guarded by: _lock
+        self.tick_host_s = 0.0                     # guarded by: _lock
+        self.barrier_wait_s = 0.0                  # guarded by: _lock
+        self.last_barrier_wait_s = 0.0             # guarded by: _lock
         self._run = threading.Event()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -409,17 +419,27 @@ class RolloutService:
         self.beats += 1
         return n
 
+    def _device_wait_s(self) -> float:
+        return sum(h.engine.device_wait_s for h in self.proxy.handles)
+
+    def _count_tick(self, ph: spans.phase, waited0: float):   # requires: _lock
+        self.tick_s += ph.seconds
+        self.tick_host_s += ph.seconds - (self._device_wait_s() - waited0)
+
     def _tick_locked(self) -> int:   # requires: _lock
-        for t in self._tenants.values():
-            if t.pre_tick is not None:
-                t.pre_tick()
-        n = self._admit_locked()
-        n += self.proxy.pump()
-        n += self._drain_completions_locked()
-        for t in self._tenants.values():
-            n += self._drain_rewards_locked(t)
-            if t.post_tick is not None:
-                t.post_tick()
+        waited0 = self._device_wait_s()
+        with spans.phase("repro.service.tick") as ph:
+            for t in self._tenants.values():
+                if t.pre_tick is not None:
+                    t.pre_tick()
+            n = self._admit_locked()
+            n += self.proxy.pump()
+            n += self._drain_completions_locked()
+            for t in self._tenants.values():
+                n += self._drain_rewards_locked(t)
+                if t.post_tick is not None:
+                    t.post_tick()
+        self._count_tick(ph, waited0)
         return n
 
     def admit(self, only: Optional[str] = None) -> int:
@@ -462,10 +482,16 @@ class RolloutService:
         traj = em.trajectory()
         if t.observe is not None and em.turns:
             t.observe(em)
-        if em.state in (EMState.FAILED, EMState.ABORTED):
-            t.stats["failed" if em.state == EMState.FAILED
-                    else "aborted"] += 1
-            return   # redundancy / staleness control absorb these
+        if em.state == EMState.FAILED:
+            t.stats["failed"] += 1
+            return
+        if em.state == EMState.ABORTED:
+            # redundancy / staleness control absorb these; what they had
+            # generated, the in-flight request's part included, is waste
+            t.stats["aborted"] += 1
+            t.stats["aborted_tokens"] += (sum(traj.loss_mask)
+                                          + em.dropped_tokens)
+            return
         if t.reward_url is None:
             t.stats["scored"] += 1
             t.stats["tokens_out"] += sum(traj.loss_mask)
@@ -532,20 +558,23 @@ class RolloutService:
         baselines' between-iteration barrier: abort leftovers, pump until
         the proxy is idle, block on pending rewards)."""
         with self._lock:
-            t = self._tenants[name]
-            if abort:
-                for em in list(t.active):
-                    em.abort()
-            pumps = 0
-            while self.proxy.busy:
-                self.proxy.pump()
+            waited0 = self._device_wait_s()
+            with spans.phase("repro.service.tick") as ph:
+                t = self._tenants[name]
+                if abort:
+                    for em in list(t.active):
+                        em.abort()
+                pumps = 0
+                while self.proxy.busy:
+                    self.proxy.pump()
+                    self._drain_completions_locked()
+                    self._drain_rewards_locked(t)
+                    pumps += 1
+                    if pumps > self.max_pump_steps:
+                        raise RuntimeError("rollout did not drain")
                 self._drain_completions_locked()
-                self._drain_rewards_locked(t)
-                pumps += 1
-                if pumps > self.max_pump_steps:
-                    raise RuntimeError("rollout did not drain")
-            self._drain_completions_locked()
-            self._drain_rewards_locked(t, block=True)
+                self._drain_rewards_locked(t, block=True)
+            self._count_tick(ph, waited0)
 
     # ------------------------------------------------------------------
     # weight-sync barrier
@@ -554,8 +583,15 @@ class RolloutService:
     def barrier(self):
         """The suspend -> update -> resume critical section: holding it
         excludes the service tick, so a weight swap never races a decode
-        step (the runner's old pump-lock contract, now a service API)."""
+        step (the runner's old pump-lock contract, now a service API).
+        The wait for the lock is the ``repro.runner.lock_wait`` span; its
+        seconds are ``last_barrier_wait_s`` while the caller holds the
+        lock, and add to ``barrier_wait_s``."""
+        waited = spans.phase("repro.runner.lock_wait").start()
         with self._lock:
+            waited.stop()
+            self.last_barrier_wait_s = waited.seconds
+            self.barrier_wait_s += waited.seconds
             yield self
 
     # ------------------------------------------------------------------
@@ -624,6 +660,14 @@ class RolloutService:
         return False
 
     # ------------------------------------------------------------------
+    def timings(self) -> Dict[str, float]:
+        """Service-wide seconds: ``tick_s`` holding the lock in ticks and
+        sync drains, ``tick_host_s`` of them not waiting on the chip,
+        ``barrier_wait_s`` waiting for the lock in barriers."""
+        with self._lock:
+            return {"tick_s": self.tick_s, "tick_host_s": self.tick_host_s,
+                    "barrier_wait_s": self.barrier_wait_s}
+
     def stats(self) -> Dict[str, Dict]:
         with self._lock:
             return {t.name: {

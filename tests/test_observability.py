@@ -255,12 +255,19 @@ def test_http_endpoint_serves_exposition_and_404s():
 def test_step_metrics_to_dict_matches_schema():
     sm = StepMetrics(step=3, wall_s=1.5, loss=0.25, reward_mean=0.5,
                      evicted=1, aborted=2, trajs=4, fetch_s=0.2,
-                     barrier_s=0.1, train_s=1.1, staleness=1)
+                     barrier_s=0.1, train_s=1.1, staleness=1,
+                     push_wait_s=0.01, lock_wait_s=0.06, sync_s=0.03,
+                     rollout_host_s=0.7, aborted_tokens=40,
+                     evicted_tokens=12)
     d = sm.to_dict()
     assert list(d) == [name for name, _ in STEP_METRICS_SCHEMA]
     for name, typ in STEP_METRICS_SCHEMA:
         assert type(d[name]) is typ, f"{name}: {type(d[name])} != {typ}"
     assert d["step"] == 3 and d["train_s"] == 1.1 and d["staleness"] == 1
+    assert (d["push_wait_s"], d["lock_wait_s"], d["sync_s"]) == \
+        (0.01, 0.06, 0.03)
+    assert d["rollout_host_s"] == 0.7
+    assert (d["aborted_tokens"], d["evicted_tokens"]) == (40, 12)
 
 
 # ---------------------------------------------------------------------------
@@ -460,8 +467,16 @@ def test_full_stack_scrape_exports_step_schema():
     for fam in ("repro_engine_decode_tokens_total",
                 "repro_buffer_consumed_total",
                 "repro_serverless_invocations_total",
-                "repro_service_completed_total"):
+                "repro_service_completed_total",
+                "repro_engine_device_wait_s_total",
+                "repro_service_aborted_tokens_total",
+                "repro_buffer_evicted_tokens_total",
+                "repro_service_tick_seconds_total",
+                "repro_service_tick_host_seconds_total",
+                "repro_service_barrier_wait_seconds_total"):
         assert fams[fam]["samples"], f"{fam} missing"
+    # a sync-mode step ticks the service on the trainer thread
+    assert fams["repro_service_tick_seconds_total"]["samples"][0][2] > 0
 
 
 # ---------------------------------------------------------------------------
