@@ -182,7 +182,7 @@ def instrument_serverless(reg: MetricsRegistry, sls) -> None:
 def instrument_service(reg: MetricsRegistry, svc) -> None:
     """Per-tenant admission/QoS counters and occupancy gauges (labels:
     tenant), the service beat, and the service lock's seconds: held in
-    ticks, waited for in weight-sync barriers."""
+    ticks, waited for in weight-sync barriers, handed to them."""
     cnt = {k: reg.counter(f"repro_service_{k}_total",
                           f"tenant {k} events", ("tenant",))
            for k in TENANT_COUNTERS}
@@ -199,7 +199,13 @@ def instrument_service(reg: MetricsRegistry, svc) -> None:
                 "tick seconds not waiting on the engines' dispatches"),
             "barrier_wait_s": reg.counter(
                 "repro_service_barrier_wait_seconds_total",
-                "seconds waiting for the service lock in barriers")}
+                "seconds waiting for the service lock in barriers"),
+            "handoffs": reg.counter(
+                "repro_service_handoffs_total",
+                "service-thread parks handing the lock to a barrier"),
+            "handoff_s": reg.counter(
+                "repro_service_handoff_seconds_total",
+                "seconds the service thread parked for barriers")}
 
     def collect():
         beats.child().set(svc.beats)

@@ -40,6 +40,8 @@ SPANS = (
     # rollout thread: one RolloutService tick, or a sync baseline's drain,
     # under the service lock (tick_s, tick_host_s)
     "repro.service.tick",
+    "repro.service.handoff",     # parked between ticks for an announced
+    #                              barrier (handoffs, handoff_s)
     "repro.engine.prefill",      # admission prefill, dispatch to readback
     "repro.engine.decode",       # decode macro-step, dispatch to readback
     #                              (both: the engine's device_wait_s)

@@ -32,11 +32,26 @@ Locking (machine-checked by ``python -m repro.analysis``):
   (:meth:`barrier`); every public entry point takes it. It is reentrant
   so barrier-context callers (the FT snapshot hook) can re-enter drain
   methods.
+- The hand-off gives ``_lock`` to a waiting barrier. :meth:`barrier`
+  counts itself in ``_barriers`` (under ``_barrier_lock``, which only
+  barrier threads take; ``_announced`` is set while the count is above
+  zero) before it blocks on ``_lock``, and out once it has let ``_lock``
+  go. Between ticks, with ``_lock`` released, the service thread parks
+  while ``_announced`` is set (``_parked`` set meanwhile), looking again
+  every ``idle_sleep``, so a barrier waits at most for the tick in
+  flight instead of racing the thread's immediate re-acquire. A barrier
+  gives a running thread up to 50 ms to park first (the tick in flight,
+  or an idle sleep), so the thread parks for every barrier; a barrier's
+  exit wakes nothing and never waits for the thread. No barrier, no
+  park: serving-only planes and the cooperative sync modes never engage
+  it.
 - ``_completed_lock`` guards every tenant's ``completed`` list — the one
   structure written from engine callback context (EnvManager
   ``on_complete`` fires under an engine's ``_step_lock`` during pump).
 - **Acquisition order: ``_lock`` -> engine ``_step_lock`` -> proxy
-  ``_lock`` -> ``TokenStream._cv`` / ``_completed_lock`` (leaves).**
+  ``_lock`` -> ``TokenStream._cv`` / ``_completed_lock`` /
+  ``_barrier_lock`` (leaves).** ``_barrier_lock`` is taken under
+  ``_lock`` only by a barrier nested in another.
   The service lock is strictly the outermost lock of the data plane:
   pump() is only ever called with ``_lock`` held, and nothing called
   from under an engine or proxy lock ever takes ``_lock`` (the stream
@@ -217,6 +232,16 @@ class RolloutService:
         self.tick_host_s = 0.0                     # guarded by: _lock
         self.barrier_wait_s = 0.0                  # guarded by: _lock
         self.last_barrier_wait_s = 0.0             # guarded by: _lock
+        # the hand-off (module docstring): barriers announced and not yet
+        # done with ``_lock``, whether there are any and whether the
+        # service thread is parked for them, and its parks
+        # (``repro.service.handoff``) and their seconds
+        self._barrier_lock = threading.Lock()
+        self._barriers = 0                         # guarded by: _barrier_lock
+        self._announced = threading.Event()
+        self._parked = threading.Event()
+        self.handoffs = 0                          # guarded by: _lock
+        self.handoff_s = 0.0                       # guarded by: _lock
         self._run = threading.Event()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -586,13 +611,43 @@ class RolloutService:
         step (the runner's old pump-lock contract, now a service API).
         The wait for the lock is the ``repro.runner.lock_wait`` span; its
         seconds are ``last_barrier_wait_s`` while the caller holds the
-        lock, and add to ``barrier_wait_s``."""
+        lock, and add to ``barrier_wait_s``. The barrier announces itself
+        first, inside that span, so the service thread yields the lock
+        after the tick in flight (:meth:`_yield_to_barriers`)."""
         waited = spans.phase("repro.runner.lock_wait").start()
-        with self._lock:
-            waited.stop()
-            self.last_barrier_wait_s = waited.seconds
-            self.barrier_wait_s += waited.seconds
-            yield self
+        with self._barrier_lock:
+            self._barriers += 1
+            self._announced.set()
+        if self._run.is_set():
+            # bounded, as a thread that crashes or closes never parks
+            self._parked.wait(timeout=0.05)
+        try:
+            with self._lock:
+                waited.stop()
+                self.last_barrier_wait_s = waited.seconds
+                self.barrier_wait_s += waited.seconds
+                yield self
+        finally:
+            with self._barrier_lock:
+                self._barriers -= 1
+                if not self._barriers:
+                    self._announced.clear()
+
+    def _yield_to_barriers(self) -> Optional[spans.phase]:
+        """The service thread, between ticks with ``_lock`` released:
+        while a barrier is announced, park until every announced barrier
+        has held the lock and let it go, or the service closes. Returns
+        the park (span ``repro.service.handoff``), None if no barrier was
+        announced."""
+        if not self._announced.is_set():
+            return None
+        with spans.phase("repro.service.handoff") as parked:
+            self._parked.set()
+            while self._announced.is_set() and \
+                    not self._stop.wait(self.idle_sleep):
+                pass
+            self._parked.clear()
+        return parked
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -602,7 +657,13 @@ class RolloutService:
             while not self._stop.is_set():
                 if not self._run.wait(timeout=0.05):
                     continue
+                parked = self._yield_to_barriers()
+                if self._stop.is_set():
+                    break
                 with self._lock:
+                    if parked is not None:
+                        self.handoffs += 1
+                        self.handoff_s += parked.seconds
                     if not self._run.is_set():
                         continue
                     n = self._tick_locked()
@@ -663,10 +724,13 @@ class RolloutService:
     def timings(self) -> Dict[str, float]:
         """Service-wide seconds: ``tick_s`` holding the lock in ticks and
         sync drains, ``tick_host_s`` of them not waiting on the chip,
-        ``barrier_wait_s`` waiting for the lock in barriers."""
+        ``barrier_wait_s`` waiting for the lock in barriers; and the
+        service thread's ``handoffs`` (parks for a barrier) and their
+        ``handoff_s``."""
         with self._lock:
             return {"tick_s": self.tick_s, "tick_host_s": self.tick_host_s,
-                    "barrier_wait_s": self.barrier_wait_s}
+                    "barrier_wait_s": self.barrier_wait_s,
+                    "handoffs": self.handoffs, "handoff_s": self.handoff_s}
 
     def stats(self) -> Dict[str, Dict]:
         with self._lock:

@@ -442,6 +442,9 @@ def test_scrape_during_traffic_returns_immutable_snapshots():
     fams = parse_prometheus(reg.render())
     assert fams["repro_engine_decode_tokens_total"]["samples"][0][2] > 0
     assert fams["repro_slo_ttft_seconds"]["samples"], "no TTFT observed"
+    # a serving-only plane takes no barrier, so its thread never parks
+    assert fams["repro_service_handoffs_total"]["samples"][0][2] == 0
+    assert svc.timings()["handoff_s"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -473,10 +476,15 @@ def test_full_stack_scrape_exports_step_schema():
                 "repro_buffer_evicted_tokens_total",
                 "repro_service_tick_seconds_total",
                 "repro_service_tick_host_seconds_total",
-                "repro_service_barrier_wait_seconds_total"):
+                "repro_service_barrier_wait_seconds_total",
+                "repro_service_handoffs_total",
+                "repro_service_handoff_seconds_total"):
         assert fams[fam]["samples"], f"{fam} missing"
-    # a sync-mode step ticks the service on the trainer thread
+    # a sync-mode step ticks the service on the trainer thread, so no
+    # service thread parks for its barrier
     assert fams["repro_service_tick_seconds_total"]["samples"][0][2] > 0
+    assert fams["repro_service_handoffs_total"]["samples"][0][2] == 0
+    assert fams["repro_service_handoff_seconds_total"]["samples"][0][2] == 0
 
 
 # ---------------------------------------------------------------------------

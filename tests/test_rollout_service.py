@@ -13,10 +13,15 @@ asserts chunk tiling internally — across
   tenant riding on the trainer's service).
 
 Plus: stride shares track configured weights under overload, full queues
-reject at submit (backpressure), and ``LiveRLRunner.close`` is idempotent
-and exception-safe (double-close, close-after-crash).
+reject at submit (backpressure), ``LiveRLRunner.close`` is idempotent
+and exception-safe (double-close, close-after-crash), and the service
+thread hands its lock to a waiting weight-sync barrier after the tick in
+flight (counted in ticks, not wall time).
 """
 import inspect
+import os
+import sys
+import threading
 import time
 
 import jax
@@ -451,3 +456,245 @@ def test_service_close_is_reentrant_and_safe(tiny_setup):
     assert svc._thread is None
     with pytest.raises(RuntimeError, match="closed"):
         svc.start()
+
+
+# ---------------------------------------------------------------------------
+# the hand-off: a waiting barrier gets the lock after the tick in flight
+# ---------------------------------------------------------------------------
+class _BusyProxy:
+    """A stand-in proxy whose pump always reports work, so the service
+    thread ticks back to back and would take its lock again at once. It
+    counts the pumps that start while a barrier is announced (``late``),
+    and can hold a pump at ``gate`` or make it raise."""
+    handles = ()
+
+    def __init__(self):
+        self.svc = None
+        self.pumps = self.late = 0
+        self.entered = threading.Event()
+        self.gate = threading.Event()
+        self.gate.set()
+        self.fail = False
+
+    def pump(self):
+        if self.svc._barriers:
+            self.late += 1
+        self.entered.set()
+        self.gate.wait()
+        if self.fail:
+            raise RuntimeError("injected pump crash")
+        self.pumps += 1
+        time.sleep(0.0002)      # a dispatch's wait: the GIL goes, the lock not
+        return 1
+
+
+def _busy_service():
+    proxy = _BusyProxy()
+    svc = RolloutService(proxy)
+    proxy.svc = svc
+    return svc, proxy
+
+
+def _await(pred, what, limit_s=10.0):
+    """Hang guard only: every assertion counts ticks or parks."""
+    deadline = time.monotonic() + limit_s
+    while not pred():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.0005)
+
+
+def _await_ticks(svc, n):
+    b0 = svc.beats
+    _await(lambda: svc.beats >= b0 + n, "service thread not ticking")
+
+
+def _await_parked(svc):
+    _await(svc._parked.is_set, "service thread never parked")
+
+
+def test_waiting_barrier_gets_the_lock_within_one_tick():
+    svc, proxy = _busy_service()
+    svc.start()
+    try:
+        for i in range(20):
+            _await_ticks(svc, 3)
+            late0 = proxy.late
+            with svc.barrier():
+                # at most the tick that raced the announcement ran after it
+                assert proxy.late - late0 <= 1, \
+                    f"barrier {i} waited {proxy.late - late0} ticks"
+                _await_parked(svc)
+                pumps = proxy.pumps
+            _await_ticks(svc, 1)                  # the thread resumes
+            assert proxy.pumps > pumps
+            t = svc.timings()
+            assert t["handoffs"] == i + 1         # one park per barrier
+            assert t["handoff_s"] > 0
+    finally:
+        svc.close()
+    assert svc.error is None
+
+
+def test_barrier_stress_keeps_ticks_and_barriers_exclusive():
+    """More barrier threads than cores against the ticking thread, with
+    a short switch interval: every barrier excludes every tick, the
+    announcement count returns to zero and the thread ticks on."""
+    svc, proxy = _busy_service()
+    holders, clashes, barriers = [0], [], 10
+    pump = proxy.pump
+
+    def exclusive_pump():
+        if holders[0]:
+            clashes.append(holders[0])
+        return pump()
+
+    proxy.pump = exclusive_pump
+
+    def worker():
+        for _ in range(barriers):
+            with svc.barrier():
+                holders[0] += 1
+                time.sleep(0)
+                holders[0] -= 1
+
+    n = 2 * (os.cpu_count() or 2) + 2
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        svc.start()
+        threads = [threading.Thread(target=worker, daemon=True)
+                   for _ in range(n)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+            assert not th.is_alive(), "a barrier hung"
+        _await_ticks(svc, 2)
+    finally:
+        sys.setswitchinterval(interval)
+        svc.close()
+    assert not clashes and svc.error is None
+    assert svc._barriers == 0 and not svc._announced.is_set()
+    assert not svc._parked.is_set()
+    assert svc.timings()["handoffs"] <= n * barriers
+
+
+def test_barrier_without_service_thread_parks_nothing():
+    """The cooperative sync path: no thread, so nothing parks."""
+    svc, proxy = _busy_service()
+    for _ in range(3):
+        with svc.barrier():
+            svc.tick()                            # a drain in the barrier
+        svc.tick()
+    assert proxy.pumps == 6 and proxy.late == 3
+    t = svc.timings()
+    assert (t["handoffs"], t["handoff_s"]) == (0, 0.0)
+    assert svc._barriers == 0 and not svc._announced.is_set()
+    assert not svc._parked.is_set()
+
+
+def _barrier_thread(svc, release):
+    got = threading.Event()
+
+    def run():
+        with svc.barrier():
+            got.set()
+            release.wait(10)
+
+    th = threading.Thread(target=run, daemon=True)
+    th.start()
+    return th, got
+
+
+@pytest.mark.parametrize("action", ["close", "pause"])
+@pytest.mark.parametrize("state", ["waiting", "holding"])
+def test_close_or_pause_with_a_barrier_waiting_returns_on_both_sides(
+        state, action):
+    """``waiting``: the barrier is blocked behind a tick in flight;
+    ``holding``: it holds the lock and the thread is parked for it."""
+    svc, proxy = _busy_service()
+    svc.start()
+    _await_ticks(svc, 2)
+    release = threading.Event()
+    if state == "waiting":
+        release.set()
+        proxy.entered.clear()
+        proxy.gate.clear()
+        assert proxy.entered.wait(10)             # the tick is in flight
+        barrier, got = _barrier_thread(svc, release)
+        _await(lambda: svc._barriers == 1, "barrier never announced")
+        assert not got.is_set()
+    else:
+        barrier, got = _barrier_thread(svc, release)
+        assert got.wait(10)
+        _await_parked(svc)
+    acting = threading.Thread(target=getattr(svc, action), daemon=True)
+    acting.start()
+    if state == "waiting":
+        proxy.gate.set()
+    elif action == "close":
+        acting.join(5)                            # the parked thread wakes
+        assert not acting.is_alive(), "close hung on a parked thread"
+    release.set()
+    for th in (acting, barrier):
+        th.join(5)
+        assert not th.is_alive(), f"{th.name} hung"
+    assert got.is_set()
+    if action == "pause":
+        beats = svc.beats
+        time.sleep(0.01)
+        assert svc.beats == beats                 # paused: no tick
+        svc.start()
+        _await_ticks(svc, 1)
+    svc.close()
+    assert svc._thread is None and svc.error is None
+
+
+def test_thread_crash_frees_a_waiting_barrier():
+    svc, proxy = _busy_service()
+    svc.start()
+    _await_ticks(svc, 2)
+    proxy.entered.clear()
+    proxy.gate.clear()
+    assert proxy.entered.wait(10)
+    release = threading.Event()
+    release.set()
+    barrier, got = _barrier_thread(svc, release)
+    _await(lambda: svc._barriers == 1, "barrier never announced")
+    proxy.fail = True
+    proxy.gate.set()                              # the tick in flight raises
+    barrier.join(5)
+    assert got.is_set() and not barrier.is_alive()
+    assert isinstance(svc.error, RuntimeError)
+    t0 = time.monotonic()
+    svc.close()
+    assert time.monotonic() - t0 < 5
+
+
+def test_drain_reenters_a_barrier_while_the_thread_is_parked(tiny_setup):
+    """Barrier-context callers (the FT snapshot hook) re-enter drain
+    entry points and may nest a barrier: the lock stays reentrant and
+    the thread parks once, until the outer barrier lets go."""
+    cfg, model, params = tiny_setup
+    with _make_service(model, params) as svc:
+        svc.register_tenant("cli")
+        ticket = svc.submit("cli", RolloutJob(
+            kind="prompt", prompt=[1, 5, 7, 9], max_new_tokens=24,
+            temperature=0.0, stop_tokens=()))
+        svc.start()
+        _await(lambda: ticket.stream.token_count() > 0, "no tokens")
+        with svc.barrier():
+            _await_parked(svc)
+            n = ticket.stream.token_count()
+            svc.drain_completions()
+            svc.drain_rewards()
+            svc.admit()
+            with svc.barrier():
+                svc.drain_tenant("cli", abort=False)   # pumps it to the end
+            assert ticket.done and ticket.stream.token_count() >= n
+            assert svc.timings()["handoffs"] == 0     # still parked
+        _await(lambda: svc.timings()["handoffs"] == 1, "thread never resumed")
+        _await_ticks(svc, 1)
+        assert svc.timings()["handoffs"] == 1
+        assert ticket.state == JobState.DONE
+        _assert_stream_matches(ticket)

@@ -79,11 +79,13 @@ def traced(tmp_path_factory):
             history = runner.run_steps(2)
     finally:
         jax.profiler.stop_trace()
+    timings = runner.service.timings()
     [path] = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)
     from jax.profiler import ProfileData
     names = {ev.name for plane in ProfileData.from_file(path).planes
              for line in plane.lines for ev in line.events}
-    return SimpleNamespace(history=history, names=names, jits=jits)
+    return SimpleNamespace(history=history, names=names, jits=jits,
+                           timings=timings)
 
 
 def test_trace_holds_every_span_and_no_other(traced):
@@ -98,6 +100,14 @@ def test_barrier_parts_make_up_barrier_s(traced):
         assert sum(parts) == pytest.approx(s.barrier_s, abs=1e-3)
         assert s.fetch_s + s.barrier_s + s.train_s <= s.wall_s
         assert s.rollout_host_s >= 0
+
+
+def test_service_thread_parks_for_the_barriers(traced):
+    """The rollout thread parks between ticks for a waiting barrier
+    (``repro.service.handoff``): at most once a barrier, one a step."""
+    t = traced.timings
+    assert 1 <= t["handoffs"] <= len(traced.history)
+    assert t["handoff_s"] > 0
 
 
 def test_roofline_readers_key_on_the_jitted_program_names(traced):
