@@ -1,0 +1,4 @@
+"""``rollout.host_share``, in the Qwen3-Next cell."""
+from benchmarks.chip import spec
+
+read = spec.reader("rollout.host_share")

@@ -1,0 +1,4 @@
+"""``runner.lock_wait_s``, in the Qwen3-Next cell."""
+from benchmarks.chip import spec
+
+read = spec.reader("runner.lock_wait_s")

@@ -5,7 +5,8 @@ Every assigned architecture gets a ``src/repro/configs/<id>.py`` exporting
 
 Layer structure is expressed as a ``block_pattern``: a tuple of
 ``(mixer, ffn)`` pairs that tiles the depth (``num_layers % len(pattern) == 0``).
-``mixer`` in {"attn", "mamba", "rwkv"}; ``ffn`` in {"mlp", "moe"}.
+``mixer`` in {"attn", "gdn", "mamba", "rwkv"} ("gdn": Gated DeltaNet linear
+attention); ``ffn`` in {"mlp", "moe"}.
 The model builder scans over pattern periods so HLO size is depth-independent.
 """
 from __future__ import annotations
@@ -15,7 +16,7 @@ import importlib
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
-MIXERS = ("attn", "mamba", "rwkv")
+MIXERS = ("attn", "gdn", "mamba", "rwkv")
 FFNS = ("mlp", "moe")
 
 
@@ -34,15 +35,21 @@ class ModelConfig:
     # layer pattern (tiled over depth)
     block_pattern: Tuple[Tuple[str, str], ...] = (("attn", "mlp"),)
     # MoE
-    num_experts: int = 0
+    num_experts: int = 0             # experts held here (this chip's share)
     top_k: int = 0
     moe_d_ff: int = 0                # per-expert hidden size (0 -> d_ff)
     num_shared_experts: int = 0
-    capacity_factor: float = 1.25
+    shared_expert_gate: bool = False  # shared expert scaled by sigmoid(x.w)
+    router_experts: int = 0          # experts the router scores (0 -> all
+    #                                  held: num_experts)
+    expert_offset: int = 0           # router id of the first held expert
     router_aux_coef: float = 0.01
     router_z_coef: float = 1e-3
     # attention details
     qk_norm: bool = False
+    attn_output_gate: bool = False   # output * sigmoid(per-head gate proj)
+    partial_rotary_factor: float = 1.0  # share of head_dim that is rotated
+    zero_centered_norm: bool = False  # every RMSNorm scales by (1 + w), w=0
     rope_theta: float = 500000.0
     sliding_window: Optional[int] = None   # set for long-context variant
     # SSM (mamba) details
@@ -51,6 +58,12 @@ class ModelConfig:
     mamba_expand: int = 2
     # RWKV details
     rwkv_head_dim: int = 64
+    # Gated DeltaNet (linear attention) details
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
     # multimodal stub frontend
     cond_len: int = 0                # conditioning prefix length (audio/vlm)
     vision_patches: int = 0          # early-fusion patch embeddings (llama4)
@@ -69,6 +82,8 @@ class ModelConfig:
             assert mixer in MIXERS and ffn in FFNS
         if self.uses_moe:
             assert self.num_experts > 0 and self.top_k > 0
+            assert (self.expert_offset + self.num_experts
+                    <= self.num_router_experts)
 
     # ------------------------------------------------------------------
     @property
@@ -86,6 +101,27 @@ class ModelConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def num_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def gdn_key_dim(self) -> int:
+        return self.linear_num_key_heads * self.linear_key_head_dim
+
+    @property
+    def gdn_value_dim(self) -> int:
+        return self.linear_num_value_heads * self.linear_value_head_dim
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels of the causal conv: q, k and v side by side."""
+        return 2 * self.gdn_key_dim + self.gdn_value_dim
 
     @property
     def mamba_d_inner(self) -> int:
@@ -136,9 +172,13 @@ class ModelConfig:
             param_dtype="float32",
             dtype="float32",
         )
+        if any(m == "gdn" for m, _ in pat):
+            kw.update(linear_num_key_heads=2, linear_num_value_heads=4,
+                      linear_key_head_dim=32, linear_value_head_dim=32)
         if self.uses_moe:
             kw.update(num_experts=min(4, self.num_experts),
-                      top_k=min(2, self.top_k),
+                      top_k=min(2, self.top_k), router_experts=0,
+                      expert_offset=0,
                       moe_d_ff=min(self.expert_d_ff, 256))
         if self.sliding_window:
             kw["sliding_window"] = 64
@@ -151,6 +191,7 @@ class ModelConfig:
         n_attn = sum(m == "attn" for m, _ in self.block_pattern) * self.num_periods
         n_mamba = sum(m == "mamba" for m, _ in self.block_pattern) * self.num_periods
         n_rwkv = sum(m == "rwkv" for m, _ in self.block_pattern) * self.num_periods
+        n_gdn = sum(m == "gdn" for m, _ in self.block_pattern) * self.num_periods
         n_moe = sum(f == "moe" for _, f in self.block_pattern) * self.num_periods
         n_mlp = sum(f == "mlp" for _, f in self.block_pattern) * self.num_periods
         p = 0
@@ -160,7 +201,14 @@ class ModelConfig:
         q = d * self.num_heads * hd
         kv = d * self.num_kv_heads * hd
         o = self.num_heads * hd * d
-        p += n_attn * (q + 2 * kv + o)
+        p += n_attn * (q * (2 if self.attn_output_gate else 1) + 2 * kv + o)
+        # gated deltanet: q/k/v/z and b/a projections, conv, A_log and
+        # dt_bias, gated norm, out_proj
+        hv, kd, vd = (self.linear_num_value_heads, self.gdn_key_dim,
+                      self.gdn_value_dim)
+        p += n_gdn * (d * (2 * kd + 2 * vd + 2 * hv)
+                      + self.gdn_conv_dim * self.linear_conv_kernel_dim
+                      + 2 * hv + self.linear_value_head_dim + vd * d)
         # mamba
         di, ds = self.mamba_d_inner, self.mamba_d_state
         p += n_mamba * (d * 2 * di            # in_proj (x and z)
@@ -176,7 +224,8 @@ class ModelConfig:
         e_ff = self.expert_d_ff
         p += n_moe * (self.num_experts * 3 * d * e_ff
                       + self.num_shared_experts * 3 * d * e_ff
-                      + d * self.num_experts)
+                      + (d if self.shared_expert_gate else 0)
+                      + d * self.num_router_experts)
         # norms (negligible)
         p += self.num_layers * 2 * d + d
         return p
@@ -221,7 +270,8 @@ ARCH_IDS = [
     "minitron-8b",
 ]
 # paper's own models, usable with the same machinery
-EXTRA_IDS = ["qwen3-8b", "qwen3-32b", "qwen2.5-7b", "tiny"]
+EXTRA_IDS = ["qwen3-8b", "qwen3-32b", "qwen2.5-7b", "qwen3-next-80b-a3b",
+             "tiny"]
 
 
 def _modname(arch: str) -> str:

@@ -195,6 +195,17 @@ class StepMetrics:
     #                                  request's included (delta)
     evicted_tokens: int = 0          # policy tokens of trajectories the
     #                                  buffer evicted as stale (delta)
+    # MoE stacks (0 otherwise). Decode, summed over scanned steps and
+    # layers (delta of the engines' counters): held experts some emitting
+    # row routed to, and the assignments that landed on held experts.
+    # Train, from the GRPO step's metrics: assignments on held experts
+    # over the layers, and per layer and held expert the largest and the
+    # mean number of assignments.
+    moe_decode_experts_touched: int = 0
+    moe_decode_tokens_here: int = 0
+    moe_train_tokens_here: int = 0
+    moe_train_load_max: float = 0.0
+    moe_train_load_mean: float = 0.0
 
     def to_dict(self) -> Dict[str, float]:
         """Stable flat schema — key order and types are
@@ -231,6 +242,11 @@ STEP_METRICS_SCHEMA = (
     ("rollout_host_s", float),
     ("aborted_tokens", int),
     ("evicted_tokens", int),
+    ("moe_decode_experts_touched", int),
+    ("moe_decode_tokens_here", int),
+    ("moe_train_tokens_here", int),
+    ("moe_train_load_max", float),
+    ("moe_train_load_mean", float),
 )
 
 
@@ -338,6 +354,7 @@ class LiveRLRunner:
         self._last_aborted_tokens = 0
         self._last_evicted_tokens = 0
         self._last_tick_host_s = 0.0
+        self._last_moe_decode = (0, 0)
         # weight-sync format: a plane with TP engine groups publishes
         # PER-SHARD chunks (engines assemble their own shards and never
         # materialize a full per-engine copy); a single-device plane
@@ -554,6 +571,13 @@ class LiveRLRunner:
     def _decode_tokens_total(self) -> int:
         return sum(h.engine.decode_tokens for h in self.proxy.handles)
 
+    def _moe_decode_totals(self):
+        """(held experts touched, assignments here) over the engines'
+        decode blocks so far: lock-free reads, as ``decode_tokens``."""
+        hs = self.proxy.handles
+        return (sum(h.engine.moe_experts_touched for h in hs),
+                sum(h.engine.moe_tokens_here for h in hs))
+
     def placement_report(self, **kw) -> List[Dict]:
         """Modeled prefill/decode latency + cost per engine pool (PerfModel
         pricing of the live placement; see LLMProxy.placement_report)."""
@@ -659,6 +683,7 @@ class LiveRLRunner:
         ab_tok_total = self._tenant.stats["aborted_tokens"]
         ev_tok_total = self.buffer.total_evicted_tokens
         host_total = self.service.tick_host_s
+        moe_decode = self._moe_decode_totals()
         sm = StepMetrics(
             step=step, wall_s=0.0,
             loss=loss,
@@ -680,13 +705,20 @@ class LiveRLRunner:
             sync_s=sync.seconds,
             rollout_host_s=host_total - self._last_tick_host_s,
             aborted_tokens=ab_tok_total - self._last_aborted_tokens,
-            evicted_tokens=ev_tok_total - self._last_evicted_tokens)
+            evicted_tokens=ev_tok_total - self._last_evicted_tokens,
+            moe_decode_experts_touched=(moe_decode[0]
+                                        - self._last_moe_decode[0]),
+            moe_decode_tokens_here=moe_decode[1] - self._last_moe_decode[1],
+            moe_train_tokens_here=int(metrics.get("moe_tokens_here", 0)),
+            moe_train_load_max=float(metrics.get("moe_load_max", 0.0)),
+            moe_train_load_mean=float(metrics.get("moe_load_mean", 0.0)))
         self._last_evicted, self._last_aborted = ev_total, ab_total
         self._last_role_switches = rs_total
         self._last_deduped = dd_total
         self._last_aborted_tokens = ab_tok_total
         self._last_evicted_tokens = ev_tok_total
         self._last_tick_host_s = host_total
+        self._last_moe_decode = moe_decode
         self.trained_log.append([t.traj_id for t in batch_trajs])
         return sm
 

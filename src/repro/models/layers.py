@@ -35,23 +35,31 @@ def dense_init(key, shape, dtype, scale: Optional[float] = None):
 # RMSNorm
 # ---------------------------------------------------------------------------
 
-def init_rmsnorm(dim: int, dtype) -> dict:
-    return {"scale": jnp.ones((dim,), dtype)}
+def init_rmsnorm(dim: int, dtype, zero_centered: bool = False) -> dict:
+    """A zero-centred norm scales by ``1 + scale``, its scale starting at
+    0 (so weight decay pulls it toward a scale of 1, not 0)."""
+    return {"scale": (jnp.zeros if zero_centered else jnp.ones)((dim,),
+                                                                 dtype)}
 
 
-def rmsnorm(p, x, eps: float):
+def _norm_scale(scale, zero_centered: bool):
+    scale = scale.astype(jnp.float32)
+    return 1.0 + scale if zero_centered else scale
+
+
+def rmsnorm(p, x, eps: float, zero_centered: bool = False):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     y = xf * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+    return (y * _norm_scale(p["scale"], zero_centered)).astype(x.dtype)
 
 
-def rmsnorm_head(scale, x, eps: float):
+def rmsnorm_head(scale, x, eps: float, zero_centered: bool = False):
     """qk-norm over the head dim; scale shape [head_dim]."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     y = xf * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+    return (y * _norm_scale(scale, zero_centered)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -63,9 +71,14 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: [..., S, hd]; positions: broadcastable to [..., S]."""
+def apply_rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
+    """x: [..., S, hd]; positions: broadcastable to [..., S]. With
+    ``rotary_dim`` < hd only the first ``rotary_dim`` dims rotate
+    (frequencies over ``rotary_dim``); the rest pass through."""
     hd = x.shape[-1]
+    if rotary_dim is not None and rotary_dim < hd:
+        rot = apply_rope(x[..., :rotary_dim], positions, theta)
+        return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)
     freqs = rope_freqs(hd, theta)                       # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
@@ -80,7 +93,7 @@ def apply_rope(x, positions, theta: float):
 
 def init_attention(key, cfg: ModelConfig) -> dict:
     d, hd = cfg.d_model, cfg.head_dim
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5 if cfg.attn_output_gate else 4)
     p = {
         "wq": dense_init(ks[0], (d, cfg.num_heads, hd), pdt(cfg)),
         "wk": dense_init(ks[1], (d, cfg.num_kv_heads, hd), pdt(cfg)),
@@ -88,23 +101,50 @@ def init_attention(key, cfg: ModelConfig) -> dict:
         "wo": dense_init(ks[3], (cfg.num_heads, hd, d), pdt(cfg),
                          scale=1.0 / math.sqrt(cfg.num_heads * hd)),
     }
+    if cfg.attn_output_gate:
+        # the per-head output gate (stored interleaved with q in HF's
+        # q_proj; a separate matrix here)
+        p["wg"] = dense_init(ks[4], (d, cfg.num_heads, hd), pdt(cfg))
     if cfg.qk_norm:
-        p["q_norm"] = jnp.ones((hd,), pdt(cfg))
-        p["k_norm"] = jnp.ones((hd,), pdt(cfg))
+        init = jnp.zeros if cfg.zero_centered_norm else jnp.ones
+        p["q_norm"] = init((hd,), pdt(cfg))
+        p["k_norm"] = init((hd,), pdt(cfg))
     return p
 
 
 def _qkv(p, cfg: ModelConfig, x, positions):
+    """q/k/v projections, qk-norm and rotary embeddings. x: [B,S,d];
+    positions [B,S], or [B] for one decode token per row. Returns
+    [B,H,S,hd] q, [B,kvH,S,hd] k and v."""
     cdt = dt(cfg)
     q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"].astype(cdt))
     k = jnp.einsum("bsd,dnh->bsnh", x, p["wk"].astype(cdt))
     v = jnp.einsum("bsd,dnh->bsnh", x, p["wv"].astype(cdt))
     if cfg.qk_norm:
-        q = rmsnorm_head(p["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm_head(p["k_norm"], k, cfg.norm_eps)
-    q = apply_rope(q.swapaxes(1, 2), positions[:, None, :], cfg.rope_theta)
-    k = apply_rope(k.swapaxes(1, 2), positions[:, None, :], cfg.rope_theta)
-    return q, k, v.swapaxes(1, 2)  # [B, H, S, hd] / [B, kvH, S, hd]
+        zc = cfg.zero_centered_norm
+        q = rmsnorm_head(p["q_norm"], q, cfg.norm_eps, zc)
+        k = rmsnorm_head(p["k_norm"], k, cfg.norm_eps, zc)
+    rot = cfg.rotary_dim if cfg.partial_rotary_factor < 1 else None
+
+    def pos():
+        return (positions[:, None, :] if positions.ndim == 2
+                else positions[:, None, None])
+    q = apply_rope(q.swapaxes(1, 2), pos(), cfg.rope_theta, rot)
+    k = apply_rope(k.swapaxes(1, 2), pos(), cfg.rope_theta, rot)
+    return q, k, v.swapaxes(1, 2)
+
+
+
+def attention_out(p, cfg: ModelConfig, x, out):
+    """Output projection of attention heads ``out`` [B,H,S,hd] computed
+    from ``x`` [B,S,d]; with ``attn_output_gate`` each head's output is
+    first scaled by ``sigmoid(x . wg)``."""
+    cdt = dt(cfg)
+    if cfg.attn_output_gate:
+        g = jnp.einsum("bsd,dnh->bnsh", x, p["wg"].astype(cdt))
+        out = (out.astype(jnp.float32)
+               * jax.nn.sigmoid(g.astype(jnp.float32))).astype(cdt)
+    return jnp.einsum("bnsh,nhd->bsd", out, p["wo"].astype(cdt))
 
 
 def _grouped_scores(q, k, cfg: ModelConfig):
@@ -163,7 +203,7 @@ def attention_fwd(p, cfg: ModelConfig, x, positions,
     q = shd(q, "batch", "act_heads", "seq", None)
     out = _attend_causal(q, k, v, cfg, window, q_chunk=q_chunk)
     out = shd(out, "batch", "act_heads", "seq", None)
-    return jnp.einsum("bnsh,nhd->bsd", out, p["wo"].astype(dt(cfg)))
+    return attention_out(p, cfg, x, out)
 
 
 def attention_decode(p, cfg: ModelConfig, x, k_cache, v_cache, positions,
@@ -180,16 +220,8 @@ def attention_decode(p, cfg: ModelConfig, x, k_cache, v_cache, positions,
     B = x.shape[0]
     S_cache = k_cache.shape[2]
     cdt = dt(cfg)
-    q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"].astype(cdt))
-    k = jnp.einsum("bsd,dnh->bsnh", x, p["wk"].astype(cdt))
-    v = jnp.einsum("bsd,dnh->bsnh", x, p["wv"].astype(cdt))
-    if cfg.qk_norm:
-        q = rmsnorm_head(p["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm_head(p["k_norm"], k, cfg.norm_eps)
-    q = apply_rope(q.swapaxes(1, 2), positions[:, None, None], cfg.rope_theta)
-    k_new = apply_rope(k.swapaxes(1, 2), positions[:, None, None],
-                       cfg.rope_theta)                    # [B,kvH,1,hd]
-    v_new = v.swapaxes(1, 2)
+    # k_new, v_new: [B,kvH,1,hd]
+    q, k_new, v_new = _qkv(p, cfg, x, positions)
 
     slot = positions % S_cache if window is not None else positions
     onehot = jax.nn.one_hot(slot, S_cache, dtype=cdt)     # [B,S_cache]
@@ -207,7 +239,7 @@ def attention_decode(p, cfg: ModelConfig, x, k_cache, v_cache, positions,
     scores = _grouped_scores(q, k_cache, cfg)             # [B,kvH,G,1,S_cache]
     # PERF(iter 2, decode): keep scores sharded over the cache-seq axis so
     # softmax stats + PV partials all-reduce ~100 KB/layer instead of
-    # all-gathering the multi-GB KV cache (EXPERIMENTS.md §Perf)
+    # all-gathering the multi-GB KV cache
     scores = shd(scores, "batch", None, None, None, "cache_seq")
     idx = jnp.arange(S_cache)[None, :]                    # [1,S_cache]
     if window is not None:
@@ -221,8 +253,7 @@ def attention_decode(p, cfg: ModelConfig, x, k_cache, v_cache, positions,
     probs = jax.nn.softmax(scores, axis=-1).astype(cdt)
     out = jnp.einsum("bkgst,bkth->bkgsh", probs, v_cache)
     out = out.reshape(B, cfg.num_heads, 1, cfg.head_dim)
-    y = jnp.einsum("bnsh,nhd->bsd", out, p["wo"].astype(cdt))
-    return y, k_cache, v_cache
+    return attention_out(p, cfg, x, out), k_cache, v_cache
 
 
 def attention_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, tables,
@@ -249,16 +280,8 @@ def attention_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, tables,
     P = tables.shape[1]
     kvH, hd = k_pool.shape[1], k_pool.shape[3]
     cdt = dt(cfg)
-    q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"].astype(cdt))
-    k = jnp.einsum("bsd,dnh->bsnh", x, p["wk"].astype(cdt))
-    v = jnp.einsum("bsd,dnh->bsnh", x, p["wv"].astype(cdt))
-    if cfg.qk_norm:
-        q = rmsnorm_head(p["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm_head(p["k_norm"], k, cfg.norm_eps)
-    q = apply_rope(q.swapaxes(1, 2), positions[:, None, None], cfg.rope_theta)
-    k_new = apply_rope(k.swapaxes(1, 2), positions[:, None, None],
-                       cfg.rope_theta)                    # [B,kvH,1,hd]
-    v_new = v.swapaxes(1, 2)
+    # k_new, v_new: [B,kvH,1,hd]
+    q, k_new, v_new = _qkv(p, cfg, x, positions)
 
     # scatter the new K/V at (page_table[b, pos//page], pos%page): the
     # write VALUE is the same bits attention_decode's one-hot update
@@ -282,8 +305,7 @@ def attention_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, tables,
     probs = jax.nn.softmax(scores, axis=-1).astype(cdt)
     out = jnp.einsum("bkgst,bkth->bkgsh", probs, vg)
     out = out.reshape(B, cfg.num_heads, 1, cfg.head_dim)
-    y = jnp.einsum("bnsh,nhd->bsd", out, p["wo"].astype(cdt))
-    return y, k_pool, v_pool
+    return attention_out(p, cfg, x, out), k_pool, v_pool
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import shd
+from repro.models import gdn as G
 from repro.models import layers as L
 from repro.models import mamba as M
 from repro.models import moe as MOE
@@ -52,6 +53,8 @@ class Model:
         k1, k2 = jax.random.split(key)
         if mixer == "attn":
             mix = L.init_attention(k1, cfg)
+        elif mixer == "gdn":
+            mix = G.init_gdn(k1, cfg)
         elif mixer == "mamba":
             mix = M.init_mamba(k1, cfg)
         elif mixer == "rwkv":
@@ -59,9 +62,10 @@ class Model:
         else:
             raise ValueError(mixer)
         ff = MOE.init_moe(k2, cfg) if ffn == "moe" else L.init_mlp(k2, cfg)
+        zc = cfg.zero_centered_norm
         return {
-            "norm1": L.init_rmsnorm(cfg.d_model, L.pdt(cfg)),
-            "norm2": L.init_rmsnorm(cfg.d_model, L.pdt(cfg)),
+            "norm1": L.init_rmsnorm(cfg.d_model, L.pdt(cfg), zc),
+            "norm2": L.init_rmsnorm(cfg.d_model, L.pdt(cfg), zc),
             mixer: mix,
             ("moe" if ffn == "moe" else "mlp"): ff,
         }
@@ -82,7 +86,8 @@ class Model:
                 keys[0], (cfg.vocab_size, cfg.d_model), L.pdt(cfg),
                 scale=0.02)},
             "layers": tuple(layers),
-            "final_norm": L.init_rmsnorm(cfg.d_model, L.pdt(cfg)),
+            "final_norm": L.init_rmsnorm(cfg.d_model, L.pdt(cfg),
+                                         cfg.zero_centered_norm),
         }
         if not cfg.tie_embeddings:
             params["lm_head"] = {"w": L.dense_init(
@@ -92,6 +97,30 @@ class Model:
     def param_count(self, params) -> int:
         return sum(x.size for x in jax.tree.leaves(params))
 
+    def _norm(self, p, x):
+        cfg = self.cfg
+        return L.rmsnorm(p, x, cfg.norm_eps, cfg.zero_centered_norm)
+
+    def _moe_aux0(self):
+        """The aux carry of the layer stack: MoE losses, and for an MoE
+        stack its counters (assignments here, the busiest held expert)."""
+        aux = {"lb_loss": jnp.zeros((), jnp.float32),
+               "z_loss": jnp.zeros((), jnp.float32)}
+        if self.cfg.uses_moe:
+            aux.update(tokens_here=jnp.zeros((), jnp.int32),
+                       load_max=jnp.zeros((), jnp.int32))
+        return aux
+
+    @staticmethod
+    def _add_moe_aux(acc, aux):
+        out = {"lb_loss": acc["lb_loss"] + aux["lb_loss"],
+               "z_loss": acc["z_loss"] + aux["z_loss"]}
+        if "tokens_here" in acc:
+            out.update(tokens_here=acc["tokens_here"] + aux["tokens_here"],
+                       load_max=jnp.maximum(acc["load_max"],
+                                            aux["load_max"]))
+        return out
+
     # ------------------------------------------------------------------
     # full-sequence forward (train / scoring)
     # ------------------------------------------------------------------
@@ -99,22 +128,24 @@ class Model:
                    aux_acc):
         cfg = self.cfg
         mixer, ffn = pattern
-        h = L.rmsnorm(bp["norm1"], x, cfg.norm_eps)
+        h = self._norm(bp["norm1"], x)
         if mixer == "attn":
-            h = L.attention_fwd(bp["attn"], cfg, h, positions,
-                                window=self.window, q_chunk=self.q_chunk)
+            with jax.named_scope("attn"):
+                h = L.attention_fwd(bp["attn"], cfg, h, positions,
+                                    window=self.window, q_chunk=self.q_chunk)
+        elif mixer == "gdn":
+            with jax.named_scope("gdn"):
+                h = G.gdn_rows(bp["gdn"], cfg, h)
         elif mixer == "mamba":
             h = M.mamba_fwd(bp["mamba"], cfg, h, chunk=self.mamba_chunk)
         else:
             h = R.rwkv_fwd(bp["rwkv"], cfg, h)
         x = x + h
-        h = L.rmsnorm(bp["norm2"], x, cfg.norm_eps)
+        h = self._norm(bp["norm2"], x)
         if ffn == "moe":
-            h, aux = MOE.moe_fwd(bp["moe"], cfg, h)
-            aux_acc = {
-                "lb_loss": aux_acc["lb_loss"] + aux["lb_loss"],
-                "z_loss": aux_acc["z_loss"] + aux["z_loss"],
-            }
+            with jax.named_scope("moe"):
+                h, aux = MOE.moe_fwd(bp["moe"], cfg, h)
+            aux_acc = self._add_moe_aux(aux_acc, aux)
         else:
             h = L.mlp_fwd(bp["mlp"], cfg, h)
         x = x + h
@@ -141,7 +172,7 @@ class Model:
         """
         cfg = self.cfg
         x, aux = self._backbone(params, tokens, cond, positions)
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = self._norm(params["final_norm"], x)
         w_out = (params["embed"]["tokens"].T if cfg.tie_embeddings
                  else params["lm_head"]["w"])
         w_out = shd(w_out.astype(L.dt(cfg)), None, "vocab")  # PERF(iter 1)
@@ -159,14 +190,13 @@ class Model:
         cfg = self.cfg
         B, S = tokens.shape
         x, aux = self._backbone(params, tokens, cond)
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = self._norm(params["final_norm"], x)
         x = shd(x, "batch", "seq", "act_embed")
         w_out = (params["embed"]["tokens"].T if cfg.tie_embeddings
                  else params["lm_head"]["w"]).astype(L.dt(cfg))
         # PERF(iter 1): contract an UNSHARDED d — gather the (data,model)-
         # sharded head weight over "data" (tens of MB) rather than letting
-        # GSPMD all-reduce [B,chunk,V] partial sums per chunk (GBs); see
-        # EXPERIMENTS.md §Perf.
+        # GSPMD all-reduce [B,chunk,V] partial sums per chunk (GBs).
         w_out = shd(w_out, None, "vocab")
         labels = jnp.concatenate(
             [tokens[:, 1:], jnp.zeros((B, 1), tokens.dtype)], axis=1)
@@ -226,8 +256,7 @@ class Model:
             lc = cond.shape[1]
             x = jnp.concatenate([cond.astype(x.dtype), x[:, lc:, :]], axis=1)
         x = shd(x, "batch", "seq", "act_embed")
-        aux = {"lb_loss": jnp.zeros((), jnp.float32),
-               "z_loss": jnp.zeros((), jnp.float32)}
+        aux = self._moe_aux0()
         if self.scan_layers:
             def body(carry, period_params):
                 x, aux = carry
@@ -257,6 +286,13 @@ class Model:
             shape = (np_, batch, cfg.num_kv_heads, clen, cfg.head_dim)
             return {"k": jnp.zeros(shape, L.dt(cfg)),
                     "v": jnp.zeros(shape, L.dt(cfg))}
+        if mixer == "gdn":
+            return {"S": jnp.zeros((np_, batch, cfg.linear_num_value_heads,
+                                    cfg.linear_key_head_dim,
+                                    cfg.linear_value_head_dim), jnp.float32),
+                    "conv": jnp.zeros((np_, batch,
+                                       cfg.linear_conv_kernel_dim - 1,
+                                       cfg.gdn_conv_dim), L.dt(cfg))}
         if mixer == "mamba":
             return {"h": jnp.zeros((np_, batch, cfg.mamba_d_inner,
                                     cfg.mamba_d_state), jnp.float32),
@@ -308,6 +344,8 @@ class Model:
     _CACHE_AXES = {
         "attn": {"k": (None, None, "cache_kv_heads", "cache_seq", None),
                  "v": (None, None, "cache_kv_heads", "cache_seq", None)},
+        "gdn": {"S": (None, None, None, None, None),
+                "conv": (None, None, None, None)},
         "mamba": {"h": (None, None, "mamba_inner", "ssm_state"),
                   "conv": (None, None, None, "mamba_inner")},
         "rwkv": {"prev_x": (None, None, None),
@@ -411,15 +449,24 @@ class Model:
     # ------------------------------------------------------------------
     # decode
     # ------------------------------------------------------------------
-    def _block_decode(self, bp, pattern, x, cache, positions):
+    def _block_decode(self, bp, pattern, x, cache, positions, live=None):
+        """One decode block; returns (x, new cache, MoE counters or None).
+        ``live`` [B] marks the rows still emitting: only they are routed
+        to the experts."""
         cfg = self.cfg
         mixer, ffn = pattern
-        h = L.rmsnorm(bp["norm1"], x, cfg.norm_eps)
+        h = self._norm(bp["norm1"], x)
         if mixer == "attn":
-            h, k_c, v_c = L.attention_decode(
-                bp["attn"], cfg, h, cache["k"], cache["v"], positions,
-                lengths=positions, window=self.window)
+            with jax.named_scope("attn"):
+                h, k_c, v_c = L.attention_decode(
+                    bp["attn"], cfg, h, cache["k"], cache["v"], positions,
+                    lengths=positions, window=self.window)
             new_cache = {"k": k_c, "v": v_c}
+        elif mixer == "gdn":
+            with jax.named_scope("gdn"):
+                h, S, conv = G.gdn_decode(bp["gdn"], cfg, h, cache["S"],
+                                          cache["conv"])
+            new_cache = {"S": S, "conv": conv}
         elif mixer == "mamba":
             h, h_state, conv = M.mamba_decode(bp["mamba"], cfg, h,
                                               cache["h"], cache["conv"])
@@ -429,12 +476,18 @@ class Model:
                                          cache["prev_x"], cache["S"])
             new_cache = {"prev_x": prev_x, "S": S}
         x = x + h
-        h = L.rmsnorm(bp["norm2"], x, cfg.norm_eps)
+        h = self._norm(bp["norm2"], x)
+        stats = None
         if ffn == "moe":
-            h, _ = MOE.moe_fwd(bp["moe"], cfg, h)
+            with jax.named_scope("moe"):
+                h, aux = MOE.moe_fwd(
+                    bp["moe"], cfg, h,
+                    live=None if live is None else live[:, None])
+            stats = {"touched": aux["touched"],
+                     "tokens_here": aux["tokens_here"]}
         else:
             h = L.mlp_fwd(bp["mlp"], cfg, h)
-        return x + h, new_cache
+        return x + h, new_cache, stats
 
     def _block_decode_paged(self, bp, pattern, x, pool_leaf, tables,
                             positions, page_size):
@@ -442,12 +495,12 @@ class Model:
         mixer, ffn = pattern
         if mixer != "attn":
             raise ValueError(f"paged decode: unsupported mixer {mixer!r}")
-        h = L.rmsnorm(bp["norm1"], x, cfg.norm_eps)
+        h = self._norm(bp["norm1"], x)
         h, k_p, v_p = L.attention_decode_paged(
             bp["attn"], cfg, h, pool_leaf["k"], pool_leaf["v"], tables,
             positions, page_size)
         x = x + h
-        h = L.rmsnorm(bp["norm2"], x, cfg.norm_eps)
+        h = self._norm(bp["norm2"], x)
         if ffn == "moe":
             h, _ = MOE.moe_fwd(bp["moe"], cfg, h)
         else:
@@ -491,7 +544,7 @@ class Model:
                 outs.append(tuple(ncs))
             new_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = self._norm(params["final_norm"], x)
         w_out = (params["embed"]["tokens"].T if cfg.tie_embeddings
                  else params["lm_head"]["w"])
         logits = jnp.einsum("bsd,dv->bsv", x, w_out.astype(L.dt(cfg)))
@@ -552,16 +605,15 @@ class Model:
         loop-invariant: every page a slot can touch is allocated at
         admission."""
         cache = self.gather_paged_cache(pool, tables)
-        toks, lps, emitted, cache = self.decode_block(
+        toks, lps, emitted, cache, stats = self.decode_block(
             params, tokens, cache, positions, keys, temperatures,
             stop_ids, budgets, sample_fn)
         pool = self.scatter_block_writes(pool, cache, tables, positions,
                                          keys.shape[0], page_size)
-        return toks, lps, emitted, pool
+        return toks, lps, emitted, pool, stats
 
     def decode_block(self, params, tokens, cache, positions, keys,
-                     temperatures, stop_ids, budgets, sample_fn,
-                     step_fn=None):
+                     temperatures, stop_ids, budgets, sample_fn):
         """K decode steps in one compiled call (``jax.lax.scan`` over the
         stacked ``keys``): the device-resident decode loop. Host dispatch,
         per-step Python overhead, and the token round-trip are amortized
@@ -581,15 +633,20 @@ class Model:
         temperatures) -> (toks [B], lps [B])`` runs inside the scanned
         body (see ``repro.rl.sampling.sample_mixed``).
 
-        Returns (toks [K,B], lps [K,B], emitted [K,B] bool, cache). Each
-        slot's emitted column is a True-prefix: host code appends exactly
-        the emitted tokens and re-derives stop/length finishing from them.
+        Returns (toks [K,B], lps [K,B], emitted [K,B] bool, cache, stats).
+        Each slot's emitted column is a True-prefix: host code appends
+        exactly the emitted tokens and re-derives stop/length finishing
+        from them. ``stats`` is empty for a stack without experts; for an
+        MoE stack it holds, summed over the K steps and the layers,
+        ``touched`` (held experts some emitting row routed to) and
+        ``tokens_here`` (the emitting rows' assignments to held experts).
         """
-        step = step_fn if step_fn is not None else self.decode_step
+        moe = self.cfg.uses_moe
 
         def body(carry, key):
             tok, pos, rem, done, cache = carry
-            logits, cache = step(params, tok, cache, pos)
+            logits, cache, stats = self._decode_step(
+                params, tok, cache, pos, ~done if moe else None)
             t, lp = sample_fn(key, logits, temperatures)
             emit = ~done
             # frozen rows re-feed their previous token at the same
@@ -602,51 +659,73 @@ class Model:
             hit_stop = jnp.any(t[:, None] == stop_ids, axis=1)
             done = done | (emit & hit_stop) | (rem <= 0)
             pos = pos + emit.astype(pos.dtype)
-            return (t[:, None], pos, rem, done, cache), (t, lp, emit)
+            return (t[:, None], pos, rem, done, cache), (t, lp, emit, stats)
 
         carry0 = (tokens, positions, budgets, budgets <= 0, cache)
-        (_, _, _, _, cache), (toks, lps, emitted) = jax.lax.scan(
+        (_, _, _, _, cache), (toks, lps, emitted, stats) = jax.lax.scan(
             body, carry0, keys)
-        return toks, lps, emitted, cache
+        return toks, lps, emitted, cache, jax.tree.map(
+            lambda a: a.sum(0), stats)
 
     def decode_step(self, params, tokens, cache, positions):
         """tokens: [B,1] int32; positions: [B] int32 (absolute positions).
 
         Returns (logits [B,V] fp32, new cache).
         """
+        logits, cache, _ = self._decode_step(params, tokens, cache,
+                                             positions)
+        return logits, cache
+
+    def _decode_step(self, params, tokens, cache, positions, live=None):
+        """:meth:`decode_step`, also returning the MoE counters of the
+        step summed over layers (an empty dict for a stack without
+        experts); ``live`` [B] as in :meth:`_block_decode`."""
         cfg = self.cfg
         x = jnp.take(params["embed"]["tokens"], tokens, axis=0)
         x = x.astype(L.dt(cfg))
         x = shd(x, "batch", "seq", "act_embed")
 
+        def add(acc, st):
+            return acc if st is None else jax.tree.map(jnp.add, acc, st)
+        stats0 = ({"touched": jnp.zeros((), jnp.int32),
+                   "tokens_here": jnp.zeros((), jnp.int32)}
+                  if cfg.uses_moe else {})
+
         if self.scan_layers:
-            def body(x, xs):
+            def body(carry, xs):
+                x, stats = carry
                 period_params, period_cache = xs
                 new_caches = []
                 for p_idx, pat in enumerate(self.cfg.block_pattern):
-                    x, nc = self._block_decode(period_params[p_idx], pat, x,
-                                               period_cache[p_idx], positions)
+                    x, nc, st = self._block_decode(
+                        period_params[p_idx], pat, x, period_cache[p_idx],
+                        positions, live)
+                    stats = add(stats, st)
                     new_caches.append(nc)
-                return x, tuple(new_caches)
-            x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+                return (x, stats), tuple(new_caches)
+            (x, stats), new_cache = jax.lax.scan(
+                body, (x, stats0), (params["layers"], cache))
         else:
             new_caches = []
+            stats = stats0
             for i in range(cfg.num_periods):
                 pp = jax.tree.map(lambda a: a[i], params["layers"])
                 pc = jax.tree.map(lambda a: a[i], cache)
                 ncs = []
                 for p_idx, pat in enumerate(cfg.block_pattern):
-                    x, nc = self._block_decode(pp[p_idx], pat, x,
-                                               pc[p_idx], positions)
+                    x, nc, st = self._block_decode(pp[p_idx], pat, x,
+                                                   pc[p_idx], positions,
+                                                   live)
+                    stats = add(stats, st)
                     ncs.append(nc)
                 new_caches.append(tuple(ncs))
             new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = self._norm(params["final_norm"], x)
         w_out = (params["embed"]["tokens"].T if cfg.tie_embeddings
                  else params["lm_head"]["w"])
         logits = jnp.einsum("bsd,dv->bsv", x, w_out.astype(L.dt(cfg)))
-        return logits[:, 0].astype(jnp.float32), new_cache
+        return logits[:, 0].astype(jnp.float32), new_cache, stats
 
     # ------------------------------------------------------------------
     # prefill (fills KV/state caches, returns last-token logits)
@@ -670,6 +749,16 @@ class Model:
         B, S = tokens.shape
         slot0 = 0 if slot is None else slot
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        # a bucket-padded prompt: positions past last_pos are masked out of
+        # recurrent state and expert routing (attention masks them causally)
+        valid = (None if last_pos is None
+                 else positions <= last_pos[:, None])
+
+        def to_slot(big, little):
+            if slot is None:
+                return little.astype(big.dtype)
+            return jax.lax.dynamic_update_slice_in_dim(
+                big, little.astype(big.dtype), slot, axis=0)
         x = jnp.take(params["embed"]["tokens"], tokens, axis=0)
         x = x.astype(L.dt(cfg))
         if cond is not None:
@@ -682,71 +771,67 @@ class Model:
             for p_idx, pat in enumerate(cfg.block_pattern):
                 bp = period_params[p_idx]
                 mixer, ffn = pat
-                h = L.rmsnorm(bp["norm1"], x, cfg.norm_eps)
+                h = self._norm(bp["norm1"], x)
                 if mixer == "attn":
-                    cdt = L.dt(cfg)
-                    q, k, v = L._qkv(bp["attn"], cfg, h, positions)
-                    ccache = period_cache[p_idx]
-                    clen = ccache["k"].shape[2]
-                    kw = k[:, :, -clen:, :] if clen < S else k
-                    vw = v[:, :, -clen:, :] if clen < S else v
-                    if self.window is not None and clen == self.window:
-                        # ring layout: token t lives in slot t % window
-                        sl = (jnp.arange(max(S - clen, 0), S) % clen)
-                        if slot is None:
-                            k_c = ccache["k"].at[:, :, sl, :].set(
-                                kw.astype(cdt))
-                            v_c = ccache["v"].at[:, :, sl, :].set(
-                                vw.astype(cdt))
+                    with jax.named_scope("attn"):
+                        cdt = L.dt(cfg)
+                        q, k, v = L._qkv(bp["attn"], cfg, h, positions)
+                        ccache = period_cache[p_idx]
+                        clen = ccache["k"].shape[2]
+                        kw = k[:, :, -clen:, :] if clen < S else k
+                        vw = v[:, :, -clen:, :] if clen < S else v
+                        if self.window is not None and clen == self.window:
+                            # ring layout: token t lives in slot t % window
+                            sl = (jnp.arange(max(S - clen, 0), S) % clen)
+                            if slot is None:
+                                k_c = ccache["k"].at[:, :, sl, :].set(
+                                    kw.astype(cdt))
+                                v_c = ccache["v"].at[:, :, sl, :].set(
+                                    vw.astype(cdt))
+                            else:
+                                # slice the slot's batch row out first: mixing
+                                # the scalar `slot` with the advanced index
+                                # `sl` in one .at[] would move the advanced
+                                # dims to the front (transposed write)
+                                def ring_write(big, little):
+                                    row = jax.lax.dynamic_slice_in_dim(
+                                        big, slot, 1, axis=0)
+                                    row = row.at[:, :, sl, :].set(
+                                        little.astype(cdt))
+                                    return jax.lax.dynamic_update_slice_in_dim(
+                                        big, row, slot, axis=0)
+                                k_c = ring_write(ccache["k"], kw)
+                                v_c = ring_write(ccache["v"], vw)
                         else:
-                            # slice the slot's batch row out first: mixing
-                            # the scalar `slot` with the advanced index
-                            # `sl` in one .at[] would move the advanced
-                            # dims to the front (transposed write)
-                            def ring_write(big, little):
-                                row = jax.lax.dynamic_slice_in_dim(
-                                    big, slot, 1, axis=0)
-                                row = row.at[:, :, sl, :].set(
-                                    little.astype(cdt))
-                                return jax.lax.dynamic_update_slice_in_dim(
-                                    big, row, slot, axis=0)
-                            k_c = ring_write(ccache["k"], kw)
-                            v_c = ring_write(ccache["v"], vw)
-                    else:
-                        k_c = jax.lax.dynamic_update_slice(
-                            ccache["k"], kw.astype(cdt), (slot0, 0, 0, 0))
-                        v_c = jax.lax.dynamic_update_slice(
-                            ccache["v"], vw.astype(cdt), (slot0, 0, 0, 0))
-                    out = L._attend_causal(q, k, v, cfg, self.window,
-                                           q_chunk=self.q_chunk)
-                    h = jnp.einsum("bnsh,nhd->bsd", out,
-                                   bp["attn"]["wo"].astype(cdt))
-                    nc = {"k": k_c, "v": v_c}
+                            k_c = jax.lax.dynamic_update_slice(
+                                ccache["k"], kw.astype(cdt), (slot0, 0, 0, 0))
+                            v_c = jax.lax.dynamic_update_slice(
+                                ccache["v"], vw.astype(cdt), (slot0, 0, 0, 0))
+                        out = L._attend_causal(q, k, v, cfg, self.window,
+                                               q_chunk=self.q_chunk)
+                        h = L.attention_out(bp["attn"], cfg, h, out)
+                        nc = {"k": k_c, "v": v_c}
+                elif mixer == "gdn":
+                    with jax.named_scope("gdn"):
+                        h, st = G.gdn_fwd(bp["gdn"], cfg, h, valid=valid,
+                                          last_pos=last_pos)
+                    nc = jax.tree.map(to_slot, period_cache[p_idx], st)
                 elif mixer == "mamba":
                     h, h_state, conv = M.mamba_fwd(
                         bp["mamba"], cfg, h, return_state=True,
                         chunk=self.mamba_chunk)
-                    nc = {"h": h_state, "conv": conv}
-                    if slot is not None:
-                        nc = jax.tree.map(
-                            lambda big, little:
-                            jax.lax.dynamic_update_slice_in_dim(
-                                big, little.astype(big.dtype), slot, axis=0),
-                            period_cache[p_idx], nc)
+                    nc = jax.tree.map(to_slot, period_cache[p_idx],
+                                      {"h": h_state, "conv": conv})
                 else:
                     h, prev_x, S_out = R.rwkv_fwd(bp["rwkv"], cfg, h,
                                                   return_state=True)
-                    nc = {"prev_x": prev_x, "S": S_out}
-                    if slot is not None:
-                        nc = jax.tree.map(
-                            lambda big, little:
-                            jax.lax.dynamic_update_slice_in_dim(
-                                big, little.astype(big.dtype), slot, axis=0),
-                            period_cache[p_idx], nc)
+                    nc = jax.tree.map(to_slot, period_cache[p_idx],
+                                      {"prev_x": prev_x, "S": S_out})
                 x = x + h
-                h = L.rmsnorm(bp["norm2"], x, cfg.norm_eps)
+                h = self._norm(bp["norm2"], x)
                 if ffn == "moe":
-                    h, _ = MOE.moe_fwd(bp["moe"], cfg, h)
+                    with jax.named_scope("moe"):
+                        h, _ = MOE.moe_fwd(bp["moe"], cfg, h, live=valid)
                 else:
                     h = L.mlp_fwd(bp["mlp"], cfg, h)
                 x = x + h
@@ -768,7 +853,7 @@ class Model:
                 outs.append(ncs)
             new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = self._norm(params["final_norm"], x)
         if last_pos is None:
             x_last = x[:, -1, :]
         else:
@@ -835,7 +920,7 @@ class Model:
             for p_idx, pat in enumerate(cfg.block_pattern):
                 bp = period_params[p_idx]
                 cdt = L.dt(cfg)
-                h = L.rmsnorm(bp["norm1"], x, cfg.norm_eps)
+                h = self._norm(bp["norm1"], x)
                 q, k, v = L._qkv(bp["attn"], cfg, h, positions)
                 pl = period_pool[p_idx]
                 k_p = write_pages(pl["k"], k)
@@ -860,10 +945,9 @@ class Model:
                     probs = jax.nn.softmax(scores, axis=-1).astype(vg.dtype)
                     out = jnp.einsum("bkgst,bkth->bkgsh", probs, vg)
                     out = out.reshape(1, cfg.num_heads, S, cfg.head_dim)
-                h = jnp.einsum("bnsh,nhd->bsd", out,
-                               bp["attn"]["wo"].astype(cdt))
+                h = L.attention_out(bp["attn"], cfg, h, out)
                 x = x + h
-                h = L.rmsnorm(bp["norm2"], x, cfg.norm_eps)
+                h = self._norm(bp["norm2"], x)
                 if pat[1] == "moe":
                     h, _ = MOE.moe_fwd(bp["moe"], cfg, h)
                 else:
@@ -887,7 +971,7 @@ class Model:
                 outs.append(ncs)
             new_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = self._norm(params["final_norm"], x)
         if last_pos is None:
             x_last = x[:, -1, :]
         else:

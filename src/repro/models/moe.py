@@ -1,16 +1,27 @@
-"""Mixture-of-Experts layer: top-k routing with grouped, capacity-based
-token dispatch (MaxText-style).
+"""Mixture-of-Experts layer: dropless top-k routing over the experts this
+chip holds.
 
-Tokens are dispatched *per group* (group = batch row), so the routing
-cumsum/scatter stay sharded over the "data" mesh axis instead of forcing a
-replicated prefix-sum over all 1M batch-tokens (which cost ~37 GiB/device in
-the flat formulation — see EXPERIMENTS.md §Perf). Expert weights are sharded
-on the expert dim over the "model" axis (expert parallelism); GSPMD lowers
-the dispatch/combine gathers into the all-to-all traffic the paper's MoE
-workloads exercise. Tokens above a group's per-expert capacity are dropped
-(standard capacity semantics).
+The router scores all ``cfg.num_router_experts`` experts (softmax over
+them, top-k, renormalised over the k). The layer holds ``cfg.num_experts``
+of them, router ids ``[expert_offset, expert_offset + num_experts)``, and
+computes only their part of the result: one chip's share of an
+expert-parallel layer, run without its exchange. A layer that holds every
+expert computes the whole layer.
 
-Aux losses: load-balance (Switch-style) + router z-loss.
+No assignment is dropped. The token-expert assignments are sorted by held
+expert, those of experts held elsewhere last, and each expert matrix is
+one grouped matmul over the sorted rows (``jax.lax.ragged_dot``); rows
+past the last group are zeroed. Long inputs (the train step) run
+in blocks of ``TOKEN_BLOCK`` tokens, each rematerialised in the backward
+pass, so a block's sorted copy of the tokens is the largest buffer.
+
+An optional shared expert runs on every token, scaled by
+``sigmoid(x . w)`` where ``cfg.shared_expert_gate``.
+
+Aux losses: load-balance (Switch-style) and router z-loss over the
+router's experts. Counters (in ``aux``): ``tokens_here``, assignments
+that landed on held experts; ``load_max``, the most that one held expert
+took; ``touched``, held experts that took at least one.
 """
 from __future__ import annotations
 
@@ -24,142 +35,120 @@ from repro.configs.base import ModelConfig
 from repro.distributed.sharding import shd
 from repro.models.layers import dense_init, dt, pdt
 
+TOKEN_BLOCK = 4096
+
 
 def init_moe(key, cfg: ModelConfig) -> dict:
     d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.num_experts
-    ks = jax.random.split(key, 7)
+    ks = jax.random.split(key, 8 if cfg.shared_expert_gate else 7)
     p = {
-        "router": dense_init(ks[0], (d, e), jnp.float32, scale=0.02),
-        "w_gate": dense_init(ks[1], (e, d, f), pdt(cfg)),
-        "w_up": dense_init(ks[2], (e, d, f), pdt(cfg)),
-        "w_down": dense_init(ks[3], (e, f, d), pdt(cfg)),
+        "router": dense_init(ks[0], (d, cfg.num_router_experts), jnp.float32,
+                             scale=0.02),
+        # each expert's matrices scaled by their own fan-in (d, then f)
+        "w_gate": dense_init(ks[1], (e, d, f), pdt(cfg),
+                             scale=1.0 / math.sqrt(d)),
+        "w_up": dense_init(ks[2], (e, d, f), pdt(cfg),
+                           scale=1.0 / math.sqrt(d)),
+        "w_down": dense_init(ks[3], (e, f, d), pdt(cfg),
+                             scale=1.0 / math.sqrt(f)),
     }
     if cfg.num_shared_experts:
         fs = f * cfg.num_shared_experts
         p["shared_gate"] = dense_init(ks[4], (d, fs), pdt(cfg))
         p["shared_up"] = dense_init(ks[5], (d, fs), pdt(cfg))
         p["shared_down"] = dense_init(ks[6], (fs, d), pdt(cfg))
+    if cfg.shared_expert_gate:
+        p["shared_expert_gate"] = dense_init(ks[7], (d, 1), pdt(cfg))
     return p
 
 
-def group_capacity(tokens_per_group: int, cfg: ModelConfig) -> int:
-    c = int(math.ceil(tokens_per_group * cfg.top_k * cfg.capacity_factor
-                      / cfg.num_experts))
-    return max(1, min(c, tokens_per_group))
-
-
-TOKENS_PER_GROUP = 256
-
-
-def moe_fwd(p, cfg: ModelConfig, x) -> Tuple[jnp.ndarray, dict]:
-    """x: [B,S,d] -> (y [B,S,d], aux {lb_loss, z_loss, expert_load}).
-
-    Tokens are split into routing groups of ~TOKENS_PER_GROUP tokens,
-    aligned with the (batch x sequence-shard) layout, so routing/cumsum/
-    scatter are fully sharded over BOTH mesh axes and never force a
-    sequence all-gather; the expert einsum's resharding (groups:
-    data x model -> experts: model) is the dispatch all-to-all, exactly as
-    in expert-parallel production systems. Per-group capacity
-    C = tokens_per_group * top_k * cf / E.
-    """
-    B, S_full, d = x.shape
-    cdt = dt(cfg)
+def _experts(p, cfg: ModelConfig, x, eid, w):
+    """The held experts' part for tokens x [T,d]: ``eid`` [T,K] is each
+    assignment's held expert (``num_experts`` where it is held
+    elsewhere), ``w`` [T,K] its weight (0 there)."""
+    T, d = x.shape
     E, K = cfg.num_experts, cfg.top_k
-    # NOTE(hillclimb): sub-grouping groups to (batch x seq-shard) granularity
-    # and sharding G over (data, model) was tried and REGRESSED badly under
-    # GSPMD (temp 20->135 GiB, collectives 56->289 GiB on qwen3-moe train_4k:
-    # the merged-dim reshape forces resharding of every routing tensor).
-    # Batch-row groups keep routing data-sharded and are the measured best.
-    nsub = 1
-    S = S_full // nsub                                      # tokens per group
-    x = x.reshape(B * nsub, S, d)
-    C = group_capacity(S, cfg)
+    cdt = dt(cfg)
+    flat = eid.reshape(T * K)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+    # the grouped matmul leaves rows past the last group undefined on the
+    # TPU (zeros on the CPU): zero them on the way in and out, so neither
+    # values nor cotangents of assignments held elsewhere leak in
+    rows = (jnp.arange(T * K) < jnp.sum(sizes))[:, None]
 
-    logits = jnp.einsum("gsd,de->gse", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))    # [G,S,E]
-    G = x.shape[0]
+    def grouped(lhs, w):
+        return jnp.where(rows, jax.lax.ragged_dot(lhs, w.astype(cdt), sizes),
+                         0)
+    xs = jnp.where(rows, x.astype(cdt)[order // K], 0)    # [T*K, d]
+    h = jax.nn.silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
+    ys = grouped(h, p["w_down"])                          # [T*K, d]
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * K, dtype=order.dtype))
+    y = jnp.einsum("tkd,tk->td", ys[inv].reshape(T, K, d), w.astype(cdt),
+                   preferred_element_type=jnp.float32)
+    return y.astype(cdt)
+
+
+def moe_fwd(p, cfg: ModelConfig, x, live=None) -> Tuple[jnp.ndarray, dict]:
+    """x: [B,S,d] -> (y [B,S,d], aux). ``live`` [B,S] bool routes only
+    those tokens to the experts (decode passes the rows still emitting).
+
+    aux: ``lb_loss``, ``z_loss``, and the int32 counters ``tokens_here``,
+    ``load_max``, ``touched``."""
+    B, S, d = x.shape
+    T = B * S
+    E, K, R = cfg.num_experts, cfg.top_k, cfg.num_router_experts
+    xf = x.reshape(T, d)
+    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))   # [T,R]
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, topk_idx = jax.lax.top_k(probs, K)           # [G,S,K]
-    gate_vals = gate_vals / jnp.clip(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, idx = jax.lax.top_k(probs, K)                # [T,K]
+    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
+    local = idx - cfg.expert_offset
+    held = (local >= 0) & (local < E)
+    if live is not None:
+        held &= live.reshape(T, 1)
+    eid = jnp.where(held, local, E)
+    w = jnp.where(held, gate_vals, 0.0)
+    if T <= TOKEN_BLOCK:
+        y = _experts(p, cfg, xf, eid, w)
+    else:
+        nb = -(-T // TOKEN_BLOCK)
+        pad = nb * TOKEN_BLOCK - T
 
-    # --- aux losses (global over all tokens) ------------------------------
-    me = probs.mean(axis=(0, 1))                            # [E]
-    assign = jax.nn.one_hot(topk_idx, E, dtype=jnp.float32).sum(2)  # [G,S,E]
-    ce = assign.mean(axis=(0, 1)) / K
-    lb_loss = E * jnp.sum(me * ce)
-    z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+        def blocks(a, fill):
+            a = jnp.pad(a, [(0, pad), (0, 0)], constant_values=fill)
+            return a.reshape((nb, TOKEN_BLOCK) + a.shape[1:])
 
-    # --- per-group dispatch indices ---------------------------------------
-    # ranks: position of each (token, k) assignment within its expert's
-    # buffer, counted over the flattened (token-major, k-minor) order.
-    flat_e = topk_idx.reshape(G, S * K)                     # [G,S*K]
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)     # [G,S*K,E]
-    ranks = jnp.cumsum(onehot, axis=1) - onehot             # rank within group
-    pos = jnp.take_along_axis(ranks, flat_e[..., None],
-                              axis=2)[..., 0]               # [G,S*K]
-    keep = pos < C
-    buf_idx = jnp.where(keep, flat_e * C + pos,
-                        E * C).reshape(G, S, K)             # OOB -> dropped
-
-    # --- scatter into per-group expert buffers ----------------------------
-    # one scatter per k (K small): avoids materializing the [G, S*K, d]
-    # gathered-token tensor that dominated memory in the flat formulation
-    xc = x.astype(cdt)
-
-    def scatter_group(xg, idxg):                            # [S,d], [S,K]
-        buf = jnp.zeros((E * C + 1, d), cdt)
-        for k in range(K):
-            buf = buf.at[idxg[:, k]].add(xg)
-        return buf
-    buffers = jax.vmap(scatter_group)(xc, buf_idx)
-    buffers = buffers[:, : E * C].reshape(G, E, C, d)
-    # groups: (data x model) -> (pod, data); experts -> model. This
-    # resharding is the dispatch all-to-all.
-    buffers = shd(buffers, "batch", "act_experts", None, None)
-
-    # --- expert compute ----------------------------------------------------
-    # PERF(iter 4, REFUTED): merging the group dim into each expert's token
-    # dim (one [d,f] dW matmul per expert) was predicted to collapse the
-    # per-group dW partials; measured temp 52.6 -> 194 GiB and flops x2.5 on
-    # jamba — the [G(data),E(model)] swap/merge forces GSPMD to replicate
-    # the dispatch tensor. THIRD refutation of the merge-the-sharded-dims
-    # family (with P9 and iter 2A): on a (data, model) mesh, keep dispatch
-    # tensors in [G, E, C, d] layout and let the per-group batched matmul
-    # stand. See EXPERIMENTS.md §Perf.
-    g = jnp.einsum("gecd,edf->gecf", buffers, p["w_gate"].astype(cdt))
-    u = jnp.einsum("gecd,edf->gecf", buffers, p["w_up"].astype(cdt))
-    h = jax.nn.silu(g) * u
-    y_e = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(cdt))
-    y_e = shd(y_e, "batch", "act_experts", None, None)
-    y_flat = y_e.reshape(G, E * C, d)
-    y_flat = jnp.concatenate(
-        [y_flat, jnp.zeros((G, 1, d), cdt)], axis=1)        # OOB row
-    # PERF(iter 2, REFUTED twice): forcing y_flat to group(data)-sharded
-    # before the combine was predicted to replace a ~4 GiB gather-reduce
-    # with a ~170 MB all-gather, but GSPMD instead replicated the routing
-    # tensors (coll 43 -> 110 GiB/dev, temp 22 -> 87 GiB). Left unconstrained.
-
-    # --- combine ------------------------------------------------------------
-    w = (gate_vals * keep.reshape(G, S, K)).astype(cdt)     # [G,S,K]
-
-    def combine_group(yg, idxg, wg):                        # [EC+1,d],[S,K],[S,K]
-        y = jnp.zeros((S, d), cdt)
-        for k in range(K):
-            y = y + yg[idxg[:, k]] * wg[:, k, None]
-        return y
-    y = jax.vmap(combine_group)(y_flat, buf_idx, w)         # [G,S,d]
-    y = y.reshape(B, S_full, d)
+        def block(_, xs):
+            return None, _experts(p, cfg, *xs)
+        _, y = jax.lax.scan(jax.checkpoint(block), None,
+                            (blocks(xf, 0), blocks(eid, E), blocks(w, 0)))
+        y = y.reshape(nb * TOKEN_BLOCK, d)[:T]
+    y = y.reshape(B, S, d)
 
     if cfg.num_shared_experts:
-        xf = x.reshape(B, S_full, d)
-        gs = jnp.einsum("bsd,df->bsf", xf, p["shared_gate"].astype(cdt))
-        us = jnp.einsum("bsd,df->bsf", xf, p["shared_up"].astype(cdt))
+        cdt = dt(cfg)
+        gs = jnp.einsum("bsd,df->bsf", x, p["shared_gate"].astype(cdt))
+        us = jnp.einsum("bsd,df->bsf", x, p["shared_up"].astype(cdt))
         hs = jax.nn.silu(gs) * us
         hs = shd(hs, "batch", "seq", "act_mlp")
-        y = y + jnp.einsum("bsf,fd->bsd", hs, p["shared_down"].astype(cdt))
+        ys = jnp.einsum("bsf,fd->bsd", hs, p["shared_down"].astype(cdt))
+        if cfg.shared_expert_gate:
+            sg = jnp.einsum("bsd,do->bso", x,
+                            p["shared_expert_gate"].astype(cdt))
+            ys = (ys.astype(jnp.float32)
+                  * jax.nn.sigmoid(sg.astype(jnp.float32))).astype(cdt)
+        y = y + ys
 
-    aux = {"lb_loss": lb_loss, "z_loss": z_loss, "expert_load": ce}
+    me = probs.mean(0)
+    ce = jnp.zeros((R,), jnp.float32).at[idx.reshape(-1)].add(1.0) / (T * K)
+    sizes = jnp.zeros((E + 1,), jnp.int32).at[eid.reshape(-1)].add(1)[:E]
+    aux = {"lb_loss": R * jnp.sum(me * ce),
+           "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+           "tokens_here": jnp.sum(sizes), "load_max": jnp.max(sizes),
+           "touched": jnp.sum((sizes > 0).astype(jnp.int32))}
     return y, aux
 
 

@@ -247,14 +247,16 @@ class InferenceEngine:
         self.donate = donate
         # Admission prefill and KV recompute (protocol step (5)) pad
         # prompts to power-of-two buckets so XLA compiles O(log max_len)
-        # shapes instead of one per distinct prompt length. Only valid for
-        # full-attention stacks: padded positions beyond last_pos are
-        # causally masked and later overwritten by decode, but a recurrent
-        # mixer (mamba/rwkv) would scan pad tokens into its state, and a
+        # shapes instead of one per distinct prompt length. Valid for
+        # stacks of full attention and Gated DeltaNet: attention masks the
+        # padded positions past last_pos causally (decode overwrites
+        # them), and the DeltaNet prefill masks them out of its conv state
+        # and recurrence (beta = g = 0 leaves the state unchanged). A
+        # mamba/rwkv scan would take pad tokens into its state, and a
         # ring-buffered sliding window could wrap them over live entries.
-        supported = (
-            model.window is None
-            and all(mixer == "attn" for mixer, _ in model.cfg.block_pattern))
+        supported = model.window is None and all(
+            mixer in ("attn", "gdn")
+            for mixer, _ in model.cfg.block_pattern)
         self._bucketed_prefill = (supported if bucketed_prefill is None
                                   else bool(bucketed_prefill) and supported)
         # Paged KV (opt-in): per-slot dense cache rows are replaced by a
@@ -366,6 +368,11 @@ class InferenceEngine:
         # prefills and decode macro-steps (the repro.engine.prefill /
         # .decode spans): the pumping thread waiting on the chip
         self.device_wait_s = 0.0                   # guarded by: _step_lock
+        # MoE stacks: over decode blocks, the held experts some emitting
+        # row routed to and the assignments that landed on held experts,
+        # summed over scanned steps and layers
+        self.moe_experts_touched = 0               # guarded by: _step_lock
+        self.moe_tokens_here = 0                   # guarded by: _step_lock
         # liveness beat for the observability watchdog: bumped at the END
         # of every step() AFTER _step_lock is released — a bare lock-free
         # counter (atomic under the GIL) so the watchdog can read it
@@ -430,10 +437,10 @@ class InferenceEngine:
                 c, sub = jax.random.split(c)
                 return c, sub
             new_key, keys = jax.lax.scan(split_body, key, None, length=K)
-            toks, lps, emitted, cache = model.decode_block(
+            toks, lps, emitted, cache, stats = model.decode_block(
                 params, tokens, cache, positions, keys, temperatures,
                 stop_ids, budgets, sample_fn=sample_mixed)
-            return toks, lps, emitted, cache, new_key
+            return toks, lps, emitted, cache, new_key, stats
 
         @functools.partial(jax.jit, donate_argnums=donate)
         def _prefill_into_slot(params, tokens, cache, slot, last_pos, key,
@@ -462,11 +469,11 @@ class InferenceEngine:
                 c, sub = jax.random.split(c)
                 return c, sub
             new_key, keys = jax.lax.scan(split_body, key, None, length=K)
-            toks, lps, emitted, pool = model.decode_block_paged(
+            toks, lps, emitted, pool, stats = model.decode_block_paged(
                 params, tokens, pool, tables, positions, keys,
                 temperatures, stop_ids, budgets, sample_fn=sample_mixed,
                 page_size=page)
-            return toks, lps, emitted, pool, new_key
+            return toks, lps, emitted, pool, new_key, stats
 
         @functools.partial(jax.jit, donate_argnums=donate)
         def _prefill_paged(params, tokens, pool, table, last_rel, key,
@@ -735,7 +742,7 @@ class InferenceEngine:
         the weights just installed."""
         with spans.span("repro.engine.recompute"):
             for i, s in enumerate(self._slots):
-                if s.active and s.pos > 0:
+                if s.active and s.pos > 1:
                     self._reprefill_slot(i)
 
     def _assemble_leaf(self, dim, parts, sharding):   # requires: _step_lock
@@ -787,12 +794,13 @@ class InferenceEngine:
         return min(b, self.max_len)
 
     def _prefill_slot(self, i: int, temperature: float,
-                      ctx_tokens: int = 0):   # requires: _step_lock
-        """Fill slot ``i``'s cache row from its tokens[:pos] — shared by
-        first admission and the protocol-(5) KV recompute. On attention-
-        only stacks the prompt is padded to a power-of-two bucket (padded
-        positions beyond last_pos are causally masked and later overwritten
-        by decode), so XLA compiles O(log max_len) prefill shapes instead
+                      ctx_tokens: int = 0,
+                      upto: Optional[int] = None):   # requires: _step_lock
+        """Fill slot ``i``'s cache row from its tokens[:upto] (default
+        tokens[:pos]) — shared by first admission and the protocol-(5) KV
+        recompute. Where ``_bucketed_prefill`` holds, the prompt is padded
+        to a power-of-two bucket (the model masks positions past the last
+        real one), so XLA compiles O(log max_len) prefill shapes instead
         of one per distinct prompt length. Returns the (token, logprob)
         sampled at the true last prompt position.
 
@@ -802,12 +810,13 @@ class InferenceEngine:
         multiple bucket; overshoot past the slot's allocation writes to
         the trash row."""
         s = self._slots[i]
+        upto = s.pos if upto is None else upto
         if not self.paged:
-            toks = s.tokens[: s.pos]
+            toks = s.tokens[:upto]
             if self._bucketed_prefill:
                 toks = toks + [0] * (self._bucket_len(len(toks)) - len(toks))
             tok_arr = jnp.asarray([toks], jnp.int32)
-            last = jnp.asarray([s.pos - 1], jnp.int32)
+            last = jnp.asarray([upto - 1], jnp.int32)
             with self._shard_ctx():
                 tok, lp, self._cache = self._prefill_jit(
                     self.params, tok_arr, self._cache, i, last,
@@ -815,7 +824,7 @@ class InferenceEngine:
             return tok, lp
         page = self.page_size
         m = ctx_tokens
-        tail = s.tokens[m: s.pos]
+        tail = s.tokens[m:upto]
         n = len(tail)
         if self._bucketed_prefill:
             sb = max(self._bucket_len(n), page)
@@ -828,7 +837,7 @@ class InferenceEngine:
         tail = tail + [0] * (sb - n)
         tok_arr = jnp.asarray([tail], jnp.int32)
         tbl = jnp.asarray(self._full_table(i))
-        last_rel = jnp.asarray([s.pos - 1 - m], jnp.int32)
+        last_rel = jnp.asarray([upto - 1 - m], jnp.int32)
         with self._shard_ctx():
             if m == 0:
                 tok, lp, self._pool = self._prefill_paged_jit(
@@ -848,7 +857,10 @@ class InferenceEngine:
             # 0 — give it exclusive pages first so the rewrite cannot
             # mutate pages shared with the prefix cache or other slots
             self._cow_slot_pages(i)
-        self._prefill_slot(i, -1.0)   # greedy: the sampled token is unused
+        # the cache holds tokens[:pos-1]: the last token is fed by the next
+        # decode step, which writes its own entry (and advances a recurrent
+        # state by it once). The sampled token is unused.
+        self._prefill_slot(i, -1.0, upto=self._slots[i].pos - 1)
         self.recomputes += 1
 
     def _cow_slot_pages(self, i: int):   # requires: _step_lock
@@ -1311,7 +1323,7 @@ class InferenceEngine:
         # settings) and hands back the advanced chain head
         with spans.phase("repro.engine.decode") as ph:
             with self._shard_ctx():
-                toks, lps, emitted, self._cache, self._key = \
+                toks, lps, emitted, self._cache, self._key, moe = \
                     self._decode_block_jit(
                         self.params, jnp.asarray(last_tokens), self._cache,
                         jnp.asarray(positions), self._key,
@@ -1321,6 +1333,7 @@ class InferenceEngine:
             toks = np.asarray(toks)          # [K, B]
             lps = np.asarray(lps)
             emitted = np.asarray(emitted)
+            self._count_moe(moe)
         self.device_wait_s += ph.seconds
         n_emitted = 0
         with spans.span("repro.engine.emit"):
@@ -1337,6 +1350,14 @@ class InferenceEngine:
                                        float(lps[k, i]))
             self._emit_step_progress(active)
         return n_emitted
+
+    def _count_moe(self, moe: Dict):   # requires: _step_lock
+        """Add a decode block's expert counters (empty for a stack
+        without experts): held experts touched and assignments here,
+        summed over its scanned steps and layers."""
+        if moe:
+            self.moe_experts_touched += int(moe["touched"])
+            self.moe_tokens_here += int(moe["tokens_here"])
 
     def _decode_macro_paged(self, active: List[int]) -> int:   # requires: _step_lock
         """Paged decode macro-step: only the ACTIVE slots ride the
@@ -1378,7 +1399,7 @@ class InferenceEngine:
             tables[j] = self._full_table(i)
         with spans.phase("repro.engine.decode") as ph:
             with self._shard_ctx():
-                toks, lps, emitted, self._pool, self._key = \
+                toks, lps, emitted, self._pool, self._key, moe = \
                     self._decode_block_paged_jit(
                         self.params, jnp.asarray(last_tokens), self._pool,
                         jnp.asarray(tables), jnp.asarray(positions),
@@ -1388,6 +1409,7 @@ class InferenceEngine:
             toks = np.asarray(toks)          # [K, ba]
             lps = np.asarray(lps)
             emitted = np.asarray(emitted)
+            self._count_moe(moe)
         self.device_wait_s += ph.seconds
         n_emitted = 0
         with spans.span("repro.engine.emit"):
@@ -1424,6 +1446,8 @@ class InferenceEngine:
                 "sharding_drops": self.sharding_drops,
                 "sync_bytes": self.sync_bytes,
                 "device_wait_s": self.device_wait_s,
+                "moe_experts_touched": self.moe_experts_touched,
+                "moe_tokens_here": self.moe_tokens_here,
             }
             with self._lock:   # nested acquisition: canonical order
                 out["rejected_too_long"] = self.rejected_too_long

@@ -62,8 +62,17 @@ def make_grpo_train_step(model: Model, optimizer: AdamW,
             ref_logprobs=batch.get("ref_logprobs"),
             clip_eps=clip_eps, kl_coef=kl_coef)
         if cfg.uses_moe:
-            loss = loss + moe_aux_loss(aux, cfg)
-            metrics["moe_lb"] = aux["lb_loss"]
+            if cfg.router_aux_coef or cfg.router_z_coef:
+                loss = loss + moe_aux_loss(aux, cfg)
+                metrics["moe_lb"] = aux["lb_loss"]
+            # assignments on held experts; the busiest held expert of any
+            # layer, and the mean over layers and held experts
+            n_moe = cfg.num_periods * sum(f == "moe"
+                                          for _, f in cfg.block_pattern)
+            metrics["moe_tokens_here"] = aux["tokens_here"]
+            metrics["moe_load_max"] = aux["load_max"].astype(jnp.float32)
+            metrics["moe_load_mean"] = (aux["tokens_here"] / (
+                n_moe * cfg.num_experts)).astype(jnp.float32)
         return loss, metrics
 
     def _grads(params, batch):

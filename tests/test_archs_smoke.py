@@ -85,10 +85,9 @@ def test_decode_step_smoke(arch, rng):
 @pytest.mark.parametrize("arch", ["llama3.2-3b", "rwkv6-7b",
                                   "jamba-v0.1-52b", "qwen3-moe-30b-a3b"])
 def test_prefill_decode_consistency(arch, rng):
-    """Prefill+decode must equal the full forward pass."""
+    """Prefill+decode must equal the full forward pass (MoE routing is
+    dropless, so prefill and the full pass route every token alike)."""
     cfg = get_config(arch).reduced()
-    if cfg.uses_moe:
-        cfg = cfg.with_(capacity_factor=float(cfg.num_experts))
     model = Model(cfg, remat=False)
     params = model.init(rng)
     B, S = 2, 64

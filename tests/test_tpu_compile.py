@@ -1,8 +1,8 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
 kernels at Qwen3-8B widths (``interpret=False``, so each must lower to a
 ``tpu_custom_call``), and the GRPO train step plus the engine's K-step
-``decode_block`` at the chip-smoke config, from ``jax.eval_shape``
-shapes. The train step must fit one chip's HBM next to the engine's own
+``decode_block`` at the chip-smoke config and at Qwen3-Next's one-chip
+share, from ``jax.eval_shape`` shapes. The train step must fit one chip's HBM next to the engine's own
 params copy. Nothing runs: these are the chip compiler's verdicts only.
 
 The topology is described inside a fixture, never at import, so every
@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.qwen3_8b import CHIP_SMOKE, CONFIG as QWEN3_8B
+from repro.configs.qwen3_next_80b_a3b import CHIP_SHARE as QWEN3_NEXT
 from repro.kernels.decode_attention import decode_attention, \
     ragged_paged_decode
 from repro.kernels.flash_attention import flash_attention
@@ -88,11 +89,21 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 def test_grpo_train_step_fits_one_v5e(one_chip):
-    model = Model(CHIP_SMOKE, remat=False)
+    _train_step_fits(one_chip, CHIP_SMOKE, SLOTS)
+
+
+def test_qwen3_next_grpo_train_step_fits_one_v5e(one_chip):
+    """The chunked delta rule and the dropless expert matmuls, forward
+    and backward, at batch 32 (8 x group 4) of 640 positions."""
+    _train_step_fits(one_chip, QWEN3_NEXT, 4 * SLOTS)
+
+
+def _train_step_fits(one_chip, cfg, rows):
+    model = Model(cfg, remat=False)
     opt = default_optimizer()
     state = _on(one_chip, jax.eval_shape(
         lambda: init_train_state(model, jax.random.PRNGKey(0), opt)))
-    batch = _on(one_chip, grpo_batch_spec(CHIP_SMOKE, SLOTS, MAX_LEN))
+    batch = _on(one_chip, grpo_batch_spec(cfg, rows, MAX_LEN))
     # un-donated, exactly as the launcher jits it
     mem = jax.jit(make_grpo_train_step(model, opt)).lower(
         state, batch).compile().memory_analysis()
@@ -106,7 +117,17 @@ def test_grpo_train_step_fits_one_v5e(one_chip):
 
 
 def test_decode_block_compiles_for_v5e(one_chip):
-    model = Model(CHIP_SMOKE, remat=False)
+    _decode_block_compiles(one_chip, CHIP_SMOKE)
+
+
+def test_qwen3_next_decode_block_compiles_for_v5e(one_chip):
+    """Three DeltaNet steps, a gated attention and four grouped expert
+    matmuls a scanned step."""
+    _decode_block_compiles(one_chip, QWEN3_NEXT)
+
+
+def _decode_block_compiles(one_chip, cfg):
+    model = Model(cfg, remat=False)
     params = _on(one_chip, jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0))))
     cache = _on(one_chip, jax.eval_shape(
