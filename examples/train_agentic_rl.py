@@ -71,8 +71,18 @@ def main():
         stats = runner.proxy.stats()
         print(f"\ndone in {time.time() - t0:.0f}s; routed by pool: "
               f"{stats['routed_by_pool']}; serverless reward calls: "
-              f"{runner.serverless.stats.invocations}; weight versions "
-              f"published: {runner.store.latest_version + 1}")
+              f"{runner.serverless.stats.invocations}; {_weight_syncs(runner)}")
+
+
+def _weight_syncs(runner) -> str:
+    """Where the engines took their weight versions: the trainer's own
+    device arrays, or the store (which publishes v0 and then only while
+    some engine pulls from it)."""
+    st = [h.engine.stats() for h in runner.proxy.handles]
+    return (f"weight versions taken on the trainer's device "
+            f"{sum(s['local_syncs'] for s in st)}, from the store "
+            f"{sum(s['store_syncs'] for s in st)} "
+            f"({runner.store.log.pushes} published)")
 
 
 if __name__ == "__main__":

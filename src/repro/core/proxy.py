@@ -463,25 +463,42 @@ class LLMProxy:
         for h in self.handles:
             h.engine.resume()
 
-    def update_all(self, params, version: int, recompute_caches: bool = True):
-        """Protocol steps (3) update + (5) KV-cache recomputation.
-        Engines already at ``version`` no-op (see
-        ``InferenceEngine.update_params``), so pulling an unchanged store
-        version — always true on iteration 0 — costs nothing instead of
-        re-prefilling every in-flight KV cache."""
+    def weight_paths(self, params
+                     ) -> Tuple[List[EngineHandle], List[EngineHandle]]:
+        """The two paths a new version of ``params`` (the trainer's) takes
+        to the engines: those that take the arrays on the device as they
+        are (no mesh, on the one device every leaf lives on;
+        ``InferenceEngine.shares_device``), and the rest — a TP group, an
+        engine on another chip — which pull it from the weight store."""
+        local: List[EngineHandle] = []
+        store: List[EngineHandle] = []
         for h in self.handles:
+            (local if h.engine.shares_device(params) else store).append(h)
+        return local, store
+
+    def update_all(self, params, version: int, recompute_caches: bool = True,
+                   handles: Optional[List[EngineHandle]] = None):
+        """Protocol steps (3) update + (5) KV-cache recomputation, on
+        ``handles`` (default: every engine). Engines already at
+        ``version`` no-op (see ``InferenceEngine.update_params``), so
+        pulling an unchanged store version — always true on iteration 0
+        — costs nothing instead of re-prefilling every in-flight KV
+        cache."""
+        for h in self.handles if handles is None else handles:
             h.engine.update_params(params, version,
                                    recompute_caches=recompute_caches)
 
     def update_all_chunks(self, chunks, version: int,
-                          recompute_caches: bool = True):
-        """Sharded weight sync fan-out: every engine assembles the new
-        version from the store's per-shard chunks straight into its own
-        placement (``InferenceEngine.update_from_chunks``) — a TP engine
-        never materializes a full unsharded param copy; a single-device
-        engine concatenates. Same no-op/recompute semantics as
+                          recompute_caches: bool = True,
+                          handles: Optional[List[EngineHandle]] = None):
+        """Sharded weight sync fan-out: every engine of ``handles``
+        (default: all) assembles the new version from the store's
+        per-shard chunks straight into its own placement
+        (``InferenceEngine.update_from_chunks``) — a TP engine never
+        materializes a full unsharded param copy; a single-device engine
+        concatenates. Same no-op/recompute semantics as
         :meth:`update_all`."""
-        for h in self.handles:
+        for h in self.handles if handles is None else handles:
             h.engine.update_from_chunks(chunks, version,
                                         recompute_caches=recompute_caches)
 

@@ -5,8 +5,10 @@ real GRPO updates) through the paper's six-step weight-sync protocol:
 
   (1) get_batch   — blocking retrieval from SampleBuffer
   (2) suspend     — LLMProxy stops admitting requests (in-flight preserved)
-  (3) update      — engines pull the latest weights from the Mooncake store
-                    (a version-matched pull is a no-op: nothing re-prefills)
+  (3) update      — every engine takes the trainer's version: one on the
+                    trainer's own device takes its arrays as they are; any
+                    other pulls it from the Mooncake store (a version-
+                    matched update is a no-op: nothing re-prefills)
   (4) resume      — pending generation continues
   (5) recomp      — in-flight trajectories' KV caches rebuilt under the new
                     weights (so they continue instead of restarting)
@@ -36,6 +38,8 @@ never races a decode step. Reward scoring is non-blocking
 buffer when its future resolves — drained in submission order so batch
 composition stays deterministic — and the weight push after each train
 step happens on its own thread, awaited only at the next suspend barrier.
+The push runs only while some engine pulls from the store: a plane whose
+engines all sit on the trainer's device publishes v0 alone.
 ``StepMetrics.decode_during_train`` counts decode tokens the engines
 generated while ``train_step`` ran (> 0 in the threaded modes, 0 in the
 synchronous baselines; see benchmarks/async_overlap.py).
@@ -183,10 +187,12 @@ class StepMetrics:
     #                                  fetch minus the OLDEST start_version
     #                                  in the batch (worst case)
     push_wait_s: float = 0.0         # barrier: last step's push awaited
+    #                                  (0 with no push in flight)
     lock_wait_s: float = 0.0         # barrier: waiting for the service lock
     sync_s: float = 0.0              # barrier, under the lock: suspend,
-    #                                  pull, update with KV recompute,
-    #                                  resume, barrier hook
+    #                                  update (device path or store pull)
+    #                                  with KV recompute, resume, barrier
+    #                                  hook
     rollout_host_s: float = 0.0      # seconds the service ticks held its
     #                                  lock not waiting on the chip (delta
     #                                  of the service's tick_host_s)
@@ -368,7 +374,8 @@ class LiveRLRunner:
                                                self._tp_chunks)
         else:
             self._chunk_dims = None
-        # publish v0 weights
+        # publish v0 weights, whatever the plane: the per-step pushes run
+        # only while some engine pulls from the store (_push_async)
         self._publish_params(self.state.params, 0)
 
     # ------------------------------------------------------------------
@@ -557,9 +564,13 @@ class LiveRLRunner:
             return push_params(self.store, params, version)
 
     def _push_async(self):
-        """Publish the new weights off-thread; the transfer overlaps the
-        resumed rollout and is awaited at the next suspend barrier."""
+        """Publish the new weights off-thread, if some engine pulls them
+        from the store (one on the trainer's device takes the trainer's
+        arrays at the barrier instead); the transfer overlaps the resumed
+        rollout and is awaited at the next suspend barrier."""
         params, version = self.state.params, self.version
+        if not self.proxy.weight_paths(params)[1]:
+            return
         self._push_future = self._push_pool.submit(
             self._publish_params, params, version)
 
@@ -623,30 +634,21 @@ class LiveRLRunner:
         self.last_batch = batch_trajs
         staleness = self.version - min(t.start_version
                                        for t in batch_trajs)
-        # (2)-(5) the ONLY rollout/trainer barrier: suspend, pull +
-        # update + in-flight KV recompute, resume — atomic w.r.t. the
-        # service tick so a weight swap never races a decode.
-        with spans.phase("repro.runner.push_wait") as push:
-            self._await_push()
+        # (2)-(5) the ONLY rollout/trainer barrier: suspend, update +
+        # in-flight KV recompute, resume — atomic w.r.t. the service tick
+        # so a weight swap never races a decode. The last step's push is
+        # awaited first, where one is in flight (some engine pulls from
+        # the store).
+        push_wait_s = 0.0
+        if self._push_future is not None:
+            with spans.phase("repro.runner.push_wait") as push:
+                self._await_push()
+            push_wait_s = push.seconds
         with self.service.barrier():
             lock_wait_s = self.service.last_barrier_wait_s
             with spans.phase("repro.runner.sync") as sync:
                 self.proxy.suspend()
-                # (5) recomp happens inside update_all[_chunks]
-                # (no-op for engines already at version v)
-                if self._tp_chunks > 1:
-                    pulled = pull_param_chunks(self.store,
-                                               self.state.params)
-                    if pulled is not None:
-                        chunks, v = pulled
-                        self.proxy.update_all_chunks(
-                            chunks, v, recompute_caches=True)
-                else:
-                    pulled = pull_params(self.store, self.state.params)
-                    if pulled is not None:
-                        params, v = pulled
-                        self.proxy.update_all(params, v,
-                                              recompute_caches=True)
+                self._update_engines()
                 self.proxy.resume()
                 if self.barrier_hook is not None:
                     # rollout snapshot point: the service lock is held,
@@ -700,7 +702,7 @@ class LiveRLRunner:
             barrier_s=t_barrier - fetch.t1,
             train_s=train.seconds,
             staleness=staleness,
-            push_wait_s=push.seconds,
+            push_wait_s=push_wait_s,
             lock_wait_s=lock_wait_s,
             sync_s=sync.seconds,
             rollout_host_s=host_total - self._last_tick_host_s,
@@ -721,6 +723,33 @@ class LiveRLRunner:
         self._last_moe_decode = moe_decode
         self.trained_log.append([t.traj_id for t in batch_trajs])
         return sm
+
+    def _update_engines(self):
+        """Protocol steps (3) and (5), under the service lock: every
+        engine takes version ``self.version`` and recomputes its
+        in-flight KV (a no-op for an engine already there). An engine
+        on the trainer's own device takes ``self.state.params`` as they
+        are, with no host round trip; the others pull the version the
+        store holds, in the plane's format."""
+        params = self.state.params
+        local, remote = self.proxy.weight_paths(params)
+        self.proxy.update_all(params, self.version, recompute_caches=True,
+                              handles=local)
+        if not remote:
+            return
+        if self._tp_chunks > 1:
+            pulled = pull_param_chunks(self.store, params)
+            if pulled is not None:
+                chunks, v = pulled
+                self.proxy.update_all_chunks(chunks, v,
+                                             recompute_caches=True,
+                                             handles=remote)
+        else:
+            pulled = pull_params(self.store, params)
+            if pulled is not None:
+                host, v = pulled
+                self.proxy.update_all(host, v, recompute_caches=True,
+                                      handles=remote)
 
     def _pack(self, trajs: List[Trajectory]) -> Dict:
         import jax.numpy as jnp

@@ -7,6 +7,16 @@ on demand over their own links, decoupling weight transfer from rollout.
 Live mode stores real jax arrays (flattened into ~bucket_mb chunks); sim
 mode tracks only sizes + versions. Transfer-time accounting reproduces the
 paper's Table 4 decomposition (push / accumulated pull / exposed pull).
+
+When the store is used and when it is bypassed (``LiveRLRunner``): the
+runner always publishes v0, and an FT restore republishes the restored
+weights. Per step, the store carries a version only to the engines that
+pull from it: a TP group (per-shard chunks), an engine on another chip,
+the disaggregated plane's engines off the trainer's chip. An engine with
+no mesh on the trainer's own device bypasses it: at the barrier it takes
+the trainer's device arrays as they are
+(``InferenceEngine.update_params``' device path), and a plane of such
+engines alone publishes nothing after v0.
 """
 from __future__ import annotations
 

@@ -38,6 +38,8 @@ ENGINE_COUNTERS = (
     ("crashes", "engine process crashes (injected or watchdog-killed)"),
     ("sharding_drops", "requests bounced off a mid-resize TP group"),
     ("sync_bytes", "weight-sync bytes pulled"),
+    ("local_syncs", "weight versions taken as the trainer's device arrays"),
+    ("store_syncs", "weight versions pulled from the weight store"),
     ("rejected_too_long", "requests rejected for context overflow"),
     ("shared_prefix_tokens", "prefill tokens served from shared prefix"),
     ("prefix_hits", "prefix-cache hits"),

@@ -25,13 +25,17 @@ SPANS = (
     # (StepMetrics.wall_s) and its phases
     "repro.runner.step",
     "repro.runner.fetch",        # (1) batch fetch: fetch_s
-    "repro.runner.push_wait",    # last step's weight push awaited: push_wait_s
+    "repro.runner.push_wait",    # last step's weight push awaited, where one
+    #                              is in flight: push_wait_s
     "repro.runner.lock_wait",    # acquiring the service lock in
     #                              RolloutService.barrier (every caller):
     #                              lock_wait_s, barrier_wait_s
     "repro.runner.sync",         # (2)-(5) under the lock: sync_s
-    "repro.weights.pull",        # an engine placing a new version on its
-    #                              devices (inside sync)
+    "repro.weights.pull",        # an engine placing a version pulled from
+    #                              the store on its devices (inside sync)
+    "repro.weights.local",       # an engine on the trainer's device taking
+    #                              the trainer's arrays as they are (inside
+    #                              sync; local_syncs)
     "repro.engine.recompute",    # (5) in-flight KV recompute (inside sync)
     "repro.runner.train",        # (6) pack, GRPO step, loss readback: train_s
     "repro.runner.pack",         # GRPO advantages, padding, upload

@@ -168,6 +168,18 @@ def _slice_chunks(parts, dim: int, idx, shape) -> np.ndarray:
     return np.ascontiguousarray(out)
 
 
+def _one_device(params):
+    """The one device every leaf of ``params`` lives on; None for host
+    arrays or leaves spread over several devices. Reads each leaf's
+    sharding, which a deleted array still has."""
+    devices = set()
+    for leaf in jax.tree.leaves(params):
+        if not isinstance(leaf, jax.Array):
+            return None
+        devices |= leaf.sharding.device_set
+    return next(iter(devices)) if len(devices) == 1 else None
+
+
 class InferenceEngine:
     """Slot-based continuous batching engine.
 
@@ -220,6 +232,10 @@ class InferenceEngine:
         self.model = model
         self.params = params                       # guarded by: _step_lock
         self.mesh = mesh
+        # the one device a mesh-less engine computes on: where the arrays
+        # it was built over live. None for a mesh engine (it places a
+        # sharded copy of its own) and for host arrays.
+        self.device = None if mesh is not None else _one_device(params)
         self.tp_group = (int(np.prod(mesh.devices.shape))
                          if mesh is not None else 1)
         self._shard_rules = (dict(shard_rules) if shard_rules is not None
@@ -319,6 +335,10 @@ class InferenceEngine:
         self.sharding_drops = 0                    # guarded by: _step_lock
         # host chunk bytes consumed by sharded weight syncs
         self.sync_bytes = 0                        # guarded by: _step_lock
+        # weight versions taken as the trainer's own device arrays, and
+        # versions placed from host arrays (pulled from the store)
+        self.local_syncs = 0                       # guarded by: _step_lock
+        self.store_syncs = 0                       # guarded by: _step_lock
         # param/cache placement: a mesh engine shards both over its group
         # (per-leaf NamedShardings; a sharded leaf never lands as a
         # whole-array copy on any one device). Done under _step_lock so
@@ -413,7 +433,12 @@ class InferenceEngine:
         # instead of copying it per call. Params are NOT donated: the same
         # param buffers are shared with the trainer, the weight store, and
         # sibling engines (build_pd_proxy passes one pytree to all of
-        # them), so donating would invalidate them for everyone else.
+        # them), so donating would invalidate them for everyone else. The
+        # converse holds for the trainer: its step must never donate a
+        # state whose params an engine holds, and a mesh-less engine on
+        # the trainer's device holds the trainer's own arrays after every
+        # barrier (update_params' device path, which refuses a deleted
+        # leaf).
         donate = (2,) if self.donate else ()
 
         @functools.partial(jax.jit, donate_argnums=donate)
@@ -667,10 +692,24 @@ class InferenceEngine:
     def resume(self):
         self.suspended = False
 
+    def shares_device(self, params) -> bool:
+        """Whether this engine can take ``params`` as they are: it has no
+        mesh, and every leaf of ``params`` is a device array on the
+        engine's one device (the trainer's own arrays, on its chip)."""
+        return self.device is not None and _one_device(params) == self.device
+
     def update_params(self, params, version: int,
                       recompute_caches: bool = True):
         """Weight sync (protocol steps (3)+(5)): swap weights and rebuild
         each in-flight trajectory's cache under the new weights.
+
+        Device path: where :meth:`shares_device` holds, the engine takes
+        ``params`` as they are, with no copy (span
+        ``repro.weights.local``, counter ``local_syncs``); a deleted
+        (donated) leaf raises ``RuntimeError`` here rather than inside a
+        later dispatch. Otherwise ``params`` (host arrays pulled from the
+        store) are placed on the engine's devices (span
+        ``repro.weights.pull``, counter ``store_syncs``).
 
         No-op when ``version`` equals the engine's current weight version
         (e.g. iteration 0, where the store still holds the weights the
@@ -683,19 +722,31 @@ class InferenceEngine:
         with self._step_lock:
             if version == self.weight_version:
                 return
-            with spans.span("repro.weights.pull"):
-                if self.mesh is not None:
-                    # per-leaf sharded placement: each leaf lands under
-                    # its NamedSharding (device_put splits host leaves
-                    # into shards), never as a whole-array copy on one
-                    # device of the group
-                    with self._shard_ctx():
-                        params = jax.device_put(params,
-                                                self._param_shardings)
-                else:
-                    # the store hands out host arrays: place them once
-                    # here, not on every dispatch that reads them
-                    params = jax.device_put(params)
+            if self.shares_device(params):
+                with spans.span("repro.weights.local"):
+                    if any(leaf.is_deleted()
+                           for leaf in jax.tree.leaves(params)):
+                        raise RuntimeError(
+                            f"weight version {version} has a deleted "
+                            "leaf: the trainer's step donated params an "
+                            "engine on its device serves")
+                self.local_syncs += 1
+            else:
+                with spans.span("repro.weights.pull"):
+                    if self.mesh is not None:
+                        # per-leaf sharded placement: each leaf lands
+                        # under its NamedSharding (device_put splits host
+                        # leaves into shards), never as a whole-array
+                        # copy on one device of the group
+                        with self._shard_ctx():
+                            params = jax.device_put(params,
+                                                    self._param_shardings)
+                    else:
+                        # the store hands out host arrays: place them
+                        # once here, on the engine's device, not on every
+                        # dispatch that reads them
+                        params = jax.device_put(params, self.device)
+                self.store_syncs += 1
             self.params = params
             self.weight_version = version
             if recompute_caches:
@@ -729,6 +780,7 @@ class InferenceEngine:
             with spans.span("repro.weights.pull"):
                 leaves = [self._assemble_leaf(dim, parts, shd)
                           for (dim, parts), shd in zip(chunks, shardings)]
+            self.store_syncs += 1
             self.params = jax.tree.unflatten(treedef, leaves)
             self.weight_version = version
             if recompute_caches:
@@ -1445,6 +1497,8 @@ class InferenceEngine:
                 "tp_group": self.tp_group,
                 "sharding_drops": self.sharding_drops,
                 "sync_bytes": self.sync_bytes,
+                "local_syncs": self.local_syncs,
+                "store_syncs": self.store_syncs,
                 "device_wait_s": self.device_wait_s,
                 "moe_experts_touched": self.moe_experts_touched,
                 "moe_tokens_here": self.moe_tokens_here,

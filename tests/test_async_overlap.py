@@ -261,6 +261,13 @@ def test_threaded_pump_matches_cooperative_greedy(tiny_setup):
 def test_one_off_trains_on_previous_batch_with_overlap(tiny_setup):
     cfg, model, params = tiny_setup
     with _make_runner(model, "one_off") as runner:
+        eng = runner.proxy.handles[0].engine
+        barriers = []
+        runner.barrier_hook = lambda r, step: barriers.append(
+            (r.version, eng.stats()["weight_version"],
+             all(a.unsafe_buffer_pointer() == b.unsafe_buffer_pointer()
+                 for a, b in zip(jax.tree.leaves(eng.params),
+                                 jax.tree.leaves(r.state.params)))))
         hist = runner.run_steps(3)
         assert [h.batch_fetched_step for h in hist] == [-1, 0, 1]
         assert all(h.batch_fetched_step < h.step for h in hist)
@@ -272,6 +279,10 @@ def test_one_off_trains_on_previous_batch_with_overlap(tiny_setup):
         assert sum(h.evicted for h in hist) == runner.buffer.total_evicted
         assert sum(h.aborted for h in hist) == runner.proxy.aborted
         assert all(np.isfinite(h.loss) for h in hist)
-        assert runner.store.latest_version == 3
+        # the engine sits on the trainer's device: at every barrier it is
+        # at the runner's version, holding the trainer's own arrays, and
+        # the store holds the v0 publication alone
+        assert barriers == [(0, 0, True), (1, 1, True), (2, 2, True)]
+        assert runner.store.log.pushes == 1
     with pytest.raises(RuntimeError):      # closed runner fails fast
         runner.run_steps(1)

@@ -18,6 +18,7 @@ from repro.core import (EngineHandle, LiveRLRunner, LLMProxy, RunnerConfig,
 from repro.core.buffer import SampleBuffer
 from repro.core.envmanager import EMState
 from repro.data.pipeline import Trajectory
+from repro.launch.mesh import make_group_mesh
 from repro.models import Model
 from repro.obs.spans import SPANS, phase
 from repro.rewards.rule_based import format_bonus_reward
@@ -26,17 +27,25 @@ from repro.rl.trainer import (default_optimizer, init_train_state,
                               make_grpo_train_step)
 
 
-def _make_runner(mode, **cfg_kw):
+def _make_runner(mode, store_engine=False, **cfg_kw):
+    """The tiny loop over one engine on the trainer's device (it takes the
+    trainer's arrays at each barrier); ``store_engine`` adds a second, a
+    one-device TP group, which pulls every version from the store."""
     model = Model(get_config("tiny"), remat=False)
     opt = default_optimizer(1e-3)
     state = init_train_state(model, jax.random.PRNGKey(0), opt)
-    eng = InferenceEngine(model, state.params, max_slots=8, max_len=320,
-                          seed=3)
+    handles = [EngineHandle(InferenceEngine(model, state.params,
+                                            max_slots=8, max_len=320,
+                                            seed=3), "H20")]
+    if store_engine:
+        handles.append(EngineHandle(InferenceEngine(
+            model, state.params, max_slots=8, max_len=320, seed=4,
+            mesh=make_group_mesh(jax.devices()[:1])), "H20"))
     kw = dict(batch_size=4, group_size=2, alpha=1, tasks=("math",),
               max_new_tokens=12, temperature=0.0)
     kw.update(cfg_kw)
     return LiveRLRunner(
-        RunnerConfig(mode=mode, **kw), LLMProxy([EngineHandle(eng, "H20")]),
+        RunnerConfig(mode=mode, **kw), LLMProxy(handles),
         state, jax.jit(make_grpo_train_step(model, opt)),
         ServerlessPlatform(), format_bonus_reward, seq_len=320)
 
@@ -62,8 +71,9 @@ class _Capture:
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """Two steps of the threaded loop under a profiler session."""
-    runner = _make_runner("rollart")
+    """Two steps of the threaded loop under a profiler session, on a
+    plane with both weight paths: the device path and the store's."""
+    runner = _make_runner("rollart", store_engine=True)
     eng = runner.proxy.handles[0].engine
     jits = {"decode": _Capture(eng._decode_block_jit),
             "prefill": _Capture(eng._prefill_jit),
@@ -133,12 +143,12 @@ def test_lock_held_before_barrier_shows_as_lock_wait():
     the ticks held the lock for less what they waited on the chip."""
     hold = 0.05
     runner = _make_runner("sync")
-    await_push, held_at = runner._await_push, []
+    await_batch, held_at = runner._await_batch, []
 
-    def hold_then_await_push():
-        await_push()
+    def await_batch_then_hold():
+        batch = await_batch()
         if held_at:
-            return
+            return batch
         held = threading.Event()
 
         def holder():
@@ -151,8 +161,9 @@ def test_lock_held_before_barrier_shows_as_lock_wait():
 
         threading.Thread(target=holder, daemon=True).start()
         held.wait()
+        return batch
 
-    runner._await_push = hold_then_await_push
+    runner._await_batch = await_batch_then_hold
     eng = runner.proxy.handles[0].engine
     with runner:
         tick0, wait0 = runner.service.tick_s, eng.device_wait_s

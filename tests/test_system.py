@@ -224,9 +224,19 @@ def test_live_pipeline_two_steps(tiny_setup):
             proxy, state, jax.jit(make_grpo_train_step(model, opt)),
             ServerlessPlatform(), format_bonus_reward,
             seq_len=256) as runner:
+        barriers = []
+        runner.barrier_hook = lambda r, step: barriers.append(
+            (r.version, eng.stats()["weight_version"],
+             all(a.unsafe_buffer_pointer() == b.unsafe_buffer_pointer()
+                 for a, b in zip(jax.tree.leaves(eng.params),
+                                 jax.tree.leaves(r.state.params)))))
         hist = runner.run_steps(2)
         assert len(hist) == 2
         assert runner.version == 2
         assert all(np.isfinite(h.loss) for h in hist)
         assert runner.serverless.stats.invocations >= 8
-        assert runner.store.latest_version == 2
+        # the engine sits on the trainer's device: at every barrier it is
+        # at the runner's version, holding the trainer's own arrays, and
+        # the store holds the v0 publication alone
+        assert barriers == [(0, 0, True), (1, 1, True)]
+        assert runner.store.log.pushes == 1
